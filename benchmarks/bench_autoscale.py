@@ -33,7 +33,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from benchmarks.fdn_common import Row, build_fdn, check
+from benchmarks.fdn_common import Row, build_fdn, check, use_compile_cache
 
 FULL_TICKS = 200_000
 SMOKE_TICKS = 50_000
@@ -181,6 +181,7 @@ def check_floor(results: Dict, floor_path: str,
 
 
 def main(argv: List[str]) -> int:
+    use_compile_cache()
     smoke = "--smoke" in argv
     floor_path = None
     json_path = "BENCH_autoscale.json"   # always emitted; --json overrides

@@ -24,7 +24,7 @@ import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
-from benchmarks.fdn_common import Row, build_fdn, check
+from benchmarks.fdn_common import Row, build_fdn, check, use_compile_cache
 from repro.chains import DataGravityPlanner, catalog
 from repro.core.scheduler import PlatformSnapshot
 
@@ -138,6 +138,7 @@ def run_bench(smoke: bool = False,
 
 
 def main(argv: List[str]) -> int:
+    use_compile_cache()
     smoke = "--smoke" in argv
     json_path = "BENCH_chain.json"       # always emitted; --json overrides
     if "--json" in argv:

@@ -35,7 +35,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from benchmarks.fdn_common import Row, check
+from benchmarks.fdn_common import Row, check, use_compile_cache
 from repro.core.loadgen import ColumnarResultSink
 from repro.core.monitoring import (ColumnarWindowSeries, MetricsRegistry,
                                    WindowSeries)
@@ -251,6 +251,7 @@ def check_floor(results: Dict, floor_path: str,
 
 
 def main(argv: List[str]) -> int:
+    use_compile_cache()
     smoke = "--smoke" in argv
     json_path = "BENCH_metrics.json"     # always emitted; --json overrides
     if "--json" in argv:

@@ -31,7 +31,7 @@ import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
-from benchmarks.fdn_common import Row, check
+from benchmarks.fdn_common import Row, check, use_compile_cache
 
 CLS = ("latency_critical", "standard", "batch")
 
@@ -133,6 +133,7 @@ def run_bench(smoke: bool = False,
 
 
 def main(argv: List[str]) -> int:
+    use_compile_cache()
     smoke = "--smoke" in argv
     json_path = "BENCH_qos.json"
     if "--json" in argv:

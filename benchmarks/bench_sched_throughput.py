@@ -67,7 +67,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from benchmarks.fdn_common import Row, build_fdn, check
+from benchmarks.fdn_common import Row, build_fdn, check, use_compile_cache
 from repro.core import scheduler as sched
 from repro.core.faults import HedgePolicy
 from repro.core.invocation_batch import InvocationBatch
@@ -349,6 +349,7 @@ def run_bench(smoke: bool = False,
 
 
 def main(argv: List[str]) -> int:
+    use_compile_cache()
     smoke = "--smoke" in argv
     floor_path = None
     json_path = "BENCH_sched.json"       # always emitted; --json overrides

@@ -36,7 +36,7 @@ import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
-from benchmarks.fdn_common import Row, build_fdn, check
+from benchmarks.fdn_common import Row, build_fdn, check, use_compile_cache
 from repro.inspector.streaming import stream_replay
 from repro.inspector.traces import synthetic_azure_counts
 
@@ -134,6 +134,7 @@ def run_bench(smoke: bool = False,
 
 
 def main(argv: List[str]) -> int:
+    use_compile_cache()
     smoke = "--smoke" in argv
     rss_limit = DEFAULT_RSS_LIMIT_MB
     json_path = "BENCH_replay.json"      # always emitted; --json overrides

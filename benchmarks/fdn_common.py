@@ -6,9 +6,13 @@ us-east), and provides the measurement/report helpers every fig*.py uses.
 """
 from __future__ import annotations
 
+import os
 import time
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Callable, Dict, List, Optional, Tuple
+
+import jax
 
 from repro.core import (FDNControlPlane, Gateway, Invocation,
                         WeightedCollaboration, RoundRobinCollaboration)
@@ -21,6 +25,23 @@ from repro.core.types import DeploymentSpec
 IMAGE_KEY = "images/sample.jpg"
 JSON_KEY = "json/coords.json"
 REMOTE_STORE = "gcp-us-east"
+
+# A persistent-cache entry is only found by a later run that looks in the
+# same directory, so the fallback path is fixed: one built from a temp
+# name, pid or time would never hit.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parent.parent / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is left to JAX; otherwise the
+    cache goes to the repo's git-ignored ``.jax_cache/``.  Returns the
+    directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def build_fdn(policy=None, platforms: Optional[List[str]] = None,

@@ -194,6 +194,8 @@ def _summarize_json(path: str, kind: str):
 
 
 def main() -> int:
+    from benchmarks.fdn_common import use_compile_cache
+    use_compile_cache()
     if len(sys.argv) > 1 and sys.argv[1] == "scenario":
         return scenario_main(sys.argv[2:])
     if len(sys.argv) > 1 and sys.argv[1] == "scenario-diff":

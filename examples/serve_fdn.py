@@ -16,7 +16,9 @@ from repro.core.types import DeploymentSpec, SLO
 from repro.core.deployment import DeploymentGenerator
 
 
-def main():
+def build_serving_fdn():
+    """The serving deployment: every TPU platform, the paper functions and
+    three model-serving functions, hedging and predictive prewarm on."""
     cp = FDNControlPlane(enable_hedging=True, predictive_prewarm=True)
     for prof in profiles.TPU_PLATFORMS.values():
         cp.create_platform(prof)
@@ -33,12 +35,21 @@ def main():
     cp.deploy(spec)
     attach_completion_hooks(cp)
     cp.policy = SLOCompositePolicy(cp.perf, cp.placement)
-    gw = Gateway(cp)
+    return cp, Gateway(cp), all_fns
 
+
+def drive(cp, gw, all_fns, duration_s: float = 240.0):
+    """Four closed-loop virtual users per function through the gateway,
+    one function after another; returns one LoadResult per function."""
+    return [run_load(cp.clock, lambda i: gw.request(i), fn, vus=4,
+                     duration_s=duration_s, sleep_s=0.5)
+            for fn in all_fns]
+
+
+def main():
+    cp, gw, all_fns = build_serving_fdn()
     print("== driving mixed workload through the FDN gateway ==")
-    for fn in all_fns:
-        run_load(cp.clock, lambda i: gw.request(i), fn, vus=4,
-                 duration_s=240.0, sleep_s=0.5)
+    drive(cp, gw, all_fns)
 
     print(f"\n{'function':>22s} -> platform decisions")
     by_fn = {}

@@ -15,9 +15,9 @@ prewarmer derives, per row,
 
 NumPy is the reference backend (float64 host arrays); a ``jax.jit``
 compiled mirror lives in ``repro.kernels.warm_forecast`` following the
-``policy_score`` pattern — NumPy stays the fallback and the parity
-oracle (tests pin byte-identical prewarm decisions from both backends),
-so the backend choice is a throughput knob, not a semantic one.  ``auto``
+``policy_score`` pattern — NumPy stays the parity oracle (tests pin
+byte-identical prewarm decisions from both backends), so the backend
+choice is a throughput knob, not a semantic one.  ``auto``
 uses NumPy below ``JAX_FORECAST_MIN`` rows (tiny states are dominated by
 dispatch overhead) and jax above it (pod-scale registries).
 """
@@ -28,6 +28,8 @@ from dataclasses import dataclass
 from typing import Optional, Tuple
 
 import numpy as np
+
+from repro.kernels import warm_forecast as wf
 
 # Minimum row count at which "auto" switches to the jitted tick.
 JAX_FORECAST_MIN = 256
@@ -47,35 +49,11 @@ def get_forecast_backend() -> str:
     return _FORECAST_BACKEND
 
 
-_wf_mod = None
-_wf_error: Optional[BaseException] = None
-
-
-def _warm_forecast_mod():
-    """The jitted forecast module, or None when jax is unavailable."""
-    global _wf_mod, _wf_error
-    if _wf_mod is None and _wf_error is None:
-        try:
-            from repro.kernels import warm_forecast as mod
-            _wf_mod = mod
-        except Exception as exc:           # missing/incompatible jax
-            _wf_error = exc
-    return _wf_mod
-
-
 def _use_jax(n_rows: int, override: Optional[str]) -> bool:
     mode = override or _FORECAST_BACKEND
     if mode == "numpy":
         return False
-    if mode == "auto" and n_rows < JAX_FORECAST_MIN:
-        return False
-    if _warm_forecast_mod() is None:
-        if mode == "jax":
-            raise RuntimeError(
-                "forecast backend 'jax' requested but the jitted tick is "
-                "unavailable") from _wf_error
-        return False
-    return True
+    return mode == "jax" or n_rows >= JAX_FORECAST_MIN
 
 
 @dataclass(frozen=True)
@@ -221,7 +199,6 @@ def predictive_tick_jax(state: ForecastState, counts: np.ndarray,
                         hold_thr: float = 0.0
                         ) -> Tuple[np.ndarray, np.ndarray]:
     """The jitted mirror: one fused device call, state written back."""
-    wf = _warm_forecast_mod()
     level, trend, idle, hist, desired, ttl = wf.predictive_tick(
         counts, state.level, state.trend, state.idle_ticks, state.hist,
         coeff, p.alpha, p.beta, p.min_demand, float(p.max_pool),

@@ -11,7 +11,8 @@ Reproduces the FaaS semantics the paper measures against:
 Execution latency comes from an ExecutionModel that can either (a) use the
 analytic cost (flops / replica_flops + data-access time from the placement
 manager) or (b) really execute the function's JAX callable on the host CPU
-once, cache the measurement, and scale it by the platform/host speed ratio.
+device once (even where an accelerator is JAX's default), cache the
+measurement, and scale it by the platform/host speed ratio.
 Everything advances on the deterministic SimClock.
 
 The queue drain is *columnar*: replicas are still assigned FIFO (warmest
@@ -31,6 +32,7 @@ import time as wall_time
 from collections import defaultdict, deque
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core import qos as qos_mod
@@ -79,18 +81,21 @@ class ExecutionModel:
         self._measured: Dict[str, float] = {}
 
     def measure_real(self, fn: FunctionSpec, payloads) -> Optional[float]:
+        """Wall seconds of one warm call of ``fn.real_fn``, run on the host
+        CPU device whatever JAX's default device is: ``host_flops`` is a
+        host CPU rate, so the measurement must come from that CPU."""
         if fn.real_fn is None:
             return None
         if fn.name not in self._measured:
-            try:
-                fn.real_fn(*payloads)              # warmup/compile
+            cpu = jax.devices("cpu")[0]
+            with jax.default_device(cpu):
+                args = [jax.device_put(p, cpu) if isinstance(p, jax.Array)
+                        else p for p in payloads]
+                fn.real_fn(*args)                  # warmup/compile
                 t0 = wall_time.perf_counter()
-                fn.real_fn(*payloads)
+                fn.real_fn(*args)
                 self._measured[fn.name] = wall_time.perf_counter() - t0
-            except Exception:
-                self._measured[fn.name] = -1.0
-        m = self._measured[fn.name]
-        return None if m < 0 else m
+        return self._measured[fn.name]
 
     def exec_seconds(self, fn: FunctionSpec, prof: PlatformProfile,
                      payloads=()) -> float:

@@ -24,7 +24,7 @@ cascade + cost + argmin once per (function, platform-set) and the batch
 router broadcasts the per-function choice to every invocation of that
 function.  The cascade runs on one of two backends:
 
-  * ``numpy`` — host arrays (the historical path; always available);
+  * ``numpy`` — host arrays (the historical path and parity oracle);
   * ``jax``   — the ``jax.jit``-compiled cascades in
     ``repro.kernels.policy_score`` (with an optional fused Pallas
     filter+argmin kernel for the composite policy).
@@ -52,6 +52,7 @@ from repro.core.behavioral import FunctionPerformanceModel
 from repro.core.data_placement import DataPlacementManager
 from repro.core.platform import TargetPlatform
 from repro.core.types import FunctionSpec, Invocation
+from repro.kernels import policy_score as ps
 
 # Minimum batch size at which the "auto" backend switches to the jitted
 # cascades (below it, host NumPy wins on dispatch overhead alone).
@@ -72,37 +73,10 @@ def get_score_backend() -> str:
     return _SCORE_BACKEND
 
 
-_ps_mod = None
-_ps_error: Optional[BaseException] = None
-
-
-def _policy_score_mod():
-    """The jitted-cascade module, or None when jax is unavailable (the
-    NumPy fallback keeps the scheduler fully functional without it)."""
-    global _ps_mod, _ps_error
-    if _ps_mod is None and _ps_error is None:
-        try:
-            from repro.kernels import policy_score as mod
-            _ps_mod = mod
-        except Exception as exc:          # missing/incompatible jax
-            _ps_error = exc
-    return _ps_mod
-
-
 def _use_jax_backend(n: int) -> bool:
     if _SCORE_BACKEND == "numpy":
         return False
-    if _SCORE_BACKEND == "auto" and n < JAX_DECIDE_MIN:
-        return False
-    if _policy_score_mod() is None:
-        if _SCORE_BACKEND == "jax":
-            # an explicit jax request must not silently measure (or CI-
-            # gate) the NumPy path — only "auto" may degrade
-            raise RuntimeError(
-                "score backend 'jax' requested but the jitted cascades "
-                "are unavailable") from _ps_error
-        return False
-    return True
+    return _SCORE_BACKEND == "jax" or n >= JAX_DECIDE_MIN
 
 
 class FnView:
@@ -486,7 +460,6 @@ class PerformanceRankedPolicy(Policy):
         return _masked(m["exec_s"], m["alive"])
 
     def _jax_decide(self, fns, snap):
-        ps = _policy_score_mod()
         m = snap.fn_matrix(fns, self.perf)
         return ps.perf_ranked_decide(m["exec_s"], m["alive"])
 
@@ -517,7 +490,6 @@ class UtilizationAwarePolicy(Policy):
         return _masked(m["exec_s"], ok)
 
     def _jax_decide(self, fns, snap):
-        ps = _policy_score_mod()
         m = snap.fn_matrix(fns, self.perf)
         return ps.utilization_decide(m["exec_s"], m["alive"],
                                      self._unloaded(snap))
@@ -621,7 +593,6 @@ class DataLocalityPolicy(Policy):
         return _masked(m["exec_s"] + m["data_s"], m["alive"])
 
     def _jax_decide(self, fns, snap):
-        ps = _policy_score_mod()
         m = snap.fn_matrix(fns, self.perf, self.placement)
         return ps.locality_decide(m["exec_s"], m["data_s"], m["alive"])
 
@@ -653,7 +624,6 @@ class WarmAwarePolicy(Policy):
         return _masked(m["exec_s"] + m["data_s"] + cold, m["alive"])
 
     def _jax_decide(self, fns, snap):
-        ps = _policy_score_mod()
         m = snap.fn_matrix(fns, self.perf, self.placement)
         return ps.warm_decide(m["exec_s"], m["data_s"], m["warm_free"],
                               snap.cold_start_s, m["alive"])
@@ -687,7 +657,6 @@ class EnergyAwarePolicy(Policy):
         return _masked(m["energy_j"], feasible)
 
     def _jax_decide(self, fns, snap):
-        ps = _policy_score_mod()
         m = snap.fn_matrix(fns, self.perf, p90=True, energy=True)
         return ps.energy_decide(m["energy_j"], m["p90_s"],
                                 _slo_vector(fns), m["alive"])
@@ -748,7 +717,6 @@ class SLOCompositePolicy(Policy):
         prediction columns (EWMA/P² gates, power model), filter cascade
         and argmin all compile into a single device program — the host
         never materializes exec/P90/energy matrices on this path."""
-        ps = _policy_score_mod()
         base = snap.fn_matrix(fns, None, self.placement)
         est = self.perf.estimator_columns(fns, snap.profs)
         nodes, loaded_w = snap.power

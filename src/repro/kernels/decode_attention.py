@@ -62,7 +62,7 @@ def _kernel(q_ref, k_ref, v_ref, len_ref, m_ref, l_ref, acc_ref, *,
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      lengths: jax.Array, *, splits: int = 4,
                      kv_block: int = 128,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     """q: (B,H,D); k,v: (B,T,KH,D); lengths: (B,). Returns (B,H,D)."""
     b, h, d = q.shape
     t, kh = k.shape[1], k.shape[2]

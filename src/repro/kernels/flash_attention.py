@@ -80,7 +80,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, kv_block: int, q_block: int,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     q_block: int = 128, kv_block: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q: (B,Sq,H,D); k,v: (B,T,KH,D) -> (B,Sq,H,D)."""
     b, sq, h, d = q.shape
     t, kh = k.shape[1], k.shape[2]

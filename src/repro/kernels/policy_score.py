@@ -10,10 +10,11 @@ per-function vectors, and returns the fused decision
 
 with ties broken to the lowest platform index, exactly like the NumPy
 ``Policy.score`` + row-argmin path in ``repro.core.scheduler`` (which
-stays as the fallback and the parity oracle — tests assert byte-identical
-platform choices under both backends).  Caveat: without jax x64, the
-cascades compute in float32 while the oracle is float64 — costs within
-float32 eps of each other could in principle flip an argmin.  Parity is
+stays as the small-batch path and the parity oracle — tests assert
+byte-identical platform choices under both backends).  Caveat: without
+jax x64, the cascades compute in float32 while the oracle is float64 —
+costs within float32 eps of each other could in principle flip an
+argmin.  Parity is
 pinned empirically on every registry scenario; if a live workload ever
 manufactures such a near-tie, prefer the numpy backend.
 
@@ -254,9 +255,9 @@ def _composite_pallas(exec_s, data_s, p90_s, wenergy, alive, unloaded,
         _composite_kernel,
         out_shape=(jax.ShapeDtypeStruct((fp, 128), _INT),
                    jax.ShapeDtypeStruct((fp, 128), _INT)),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY
                                if interpret else pltpu.VMEM)] * 7,
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY
                                 if interpret else pltpu.VMEM),) * 2,
         interpret=interpret,
     )(*args)
@@ -292,11 +293,14 @@ def _fused_composite_kernel(ewma_v_ref, ewma_n_ref, analytic_ref,
     p90 = jnp.where(resp_n_ref[...] >= 10, resp_h2_ref[...],
                     exec_s * 1.5)
     energy = (exec_s * nodes_ref[...]) * loadedw_ref[...]
+    # Graceful degrade as boolean algebra: Mosaic cannot lower a select
+    # between two bool vectors (an i8 -> i1 truncation), so
+    # ``where(ok.any(1), ok, alive)`` is written ``ok | (alive & ~ok.any(1))``.
     alive = alive_ref[...] > 0
     ok = alive & (unloaded_ref[...] > 0)
-    ok = jnp.where(ok.any(axis=1, keepdims=True), ok, alive)
+    ok = ok | (alive & ~ok.any(axis=1, keepdims=True))
     feasible = ok & (p90 <= slo_ref[...])
-    feasible = jnp.where(feasible.any(axis=1, keepdims=True), feasible, ok)
+    feasible = feasible | (ok & ~feasible.any(axis=1, keepdims=True))
     cost = (exec_s + data_ref[...]) + weight_ref[...] * energy
     masked = jnp.where(feasible, cost, jnp.inf)
     row_min = masked.min(axis=1, keepdims=True)
@@ -339,9 +343,9 @@ def _fused_composite_pallas(ewma_v, ewma_n, analytic_s, resp_h2, resp_n,
         _fused_composite_kernel,
         out_shape=(jax.ShapeDtypeStruct((fp, 128), _INT),
                    jax.ShapeDtypeStruct((fp, 128), _INT)),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY
                                if interpret else pltpu.VMEM)] * 12,
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY
                                 if interpret else pltpu.VMEM),) * 2,
         interpret=interpret,
     )(*args)

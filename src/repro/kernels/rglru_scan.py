@@ -47,7 +47,7 @@ def _kernel(a_ref, b_ref, h_ref, carry_ref, *, chunk: int):
                                              "interpret"))
 def rglru_scan(a: jax.Array, b: jax.Array, *, chunk: int = 64,
                width_block: int = 128,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool) -> jax.Array:
     """a, b: (B, S, W) -> h: (B, S, W) with h_t = a_t*h_{t-1} + b_t."""
     bs, s, w = a.shape
     chunk = min(chunk, s)

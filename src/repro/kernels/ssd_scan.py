@@ -74,7 +74,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, state_ref,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
              Cm: jax.Array, *, chunk: int = 64,
-             interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+             interpret: bool) -> Tuple[jax.Array, jax.Array]:
     """x: (B,S,H,P); dt: (B,S,H); A: (H,); Bm/Cm: (B,S,G,N).
 
     Returns (y: (B,S,H,P), final_state: (B,H,P,N)). G must divide H.

@@ -6,8 +6,8 @@ One call advances every managed row: Holt level/trend smoothing of the
 tick's arrival counts, inter-arrival-gap histogram scatter (one-hot — the
 row count is tiny relative to a device pass), Little's-law desired-pool
 sizing, and the gap-quantile keep-alive TTL.  The NumPy reference in
-``repro.autoscale.forecast`` stays the fallback and the parity oracle:
-tests pin byte-identical prewarm decisions (desired pools and TTL ticks)
+``repro.autoscale.forecast`` stays the small-state path and the parity
+oracle: tests pin byte-identical prewarm decisions (desired pools and TTL ticks)
 from both backends on seeded arrival streams.  Caveat mirrors
 ``policy_score``: without jax x64 this computes in float32 while the
 oracle is float64 — a demand landing exactly on an integer in one

@@ -200,8 +200,6 @@ def lower_cell(cfg: ModelConfig, shape: InputShape, mesh,
         compiled = lowered.compile()
         res.compile_s = time.time() - t0
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):      # older JAX: list of dicts
-            ca = ca[0] if ca else {}
         res.flops_per_dev = float(ca.get("flops", 0.0))
         res.bytes_per_dev = float(ca.get("bytes accessed", 0.0))
         try:

@@ -1,43 +1,26 @@
 """Mesh construction for the production pod slices and FDN target platforms.
 
 ``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
-importing this module never touches jax device state.
-
-Version compat: ``jax.sharding.AxisType`` only exists on newer JAX; on
-older installs ``jax.make_mesh`` takes no ``axis_types`` and every axis is
-Auto by default, which is exactly what we request — so the shim just drops
-the argument.  Always build meshes through this module, never by importing
-``AxisType`` directly.
+importing this module never touches jax device state.  Every axis is
+``AxisType.Auto``: the model code shards through GSPMD annotations.
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:                                    # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                     # older jax: all axes are Auto
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
-def _mk(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mk(shape, axes)
-
-
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    return _mk(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model_parallel: Optional[int] = None) -> Mesh:

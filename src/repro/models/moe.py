@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.params import Spec
-from repro.sharding import constrain, shard_map
+from repro.sharding import constrain
 
 
 def moe_specs(cfg: ModelConfig) -> Dict[str, Any]:
@@ -176,7 +176,7 @@ def _sorted_shard_map(cfg: ModelConfig, p: Dict, x: jax.Array):
         return y, aux
 
     wspec = P(None, None, "model")
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None), wspec, wspec,
                   P(None, "model", None)),

@@ -17,7 +17,7 @@ from repro.configs.base import ModelConfig, MOE, VLM
 from repro.models import layers as nn
 from repro.models import moe as moe_mod
 from repro.models.params import Spec, stack
-from repro.sharding import constrain, shard_map
+from repro.sharding import constrain
 
 # ---------------------------------------------------------------------------
 # Parameter declaration
@@ -332,7 +332,7 @@ def _flash_decode_shmap(q, kc, vc, k_new, v_new, slot, pos, mesh):
         out = acc_g / jnp.maximum(l_g, 1e-30)
         return out.reshape(b_loc, 1, h, dh).astype(q.dtype), kc, vc
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, None, None, None), P(dp, "model", None, None),
                   P(dp, "model", None, None), P(dp, None, None, None),

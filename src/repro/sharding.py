@@ -45,19 +45,6 @@ RULES = {
 }
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compat ``shard_map``: newer JAX exposes ``jax.shard_map``
-    with a ``check_vma`` flag; older releases only have
-    ``jax.experimental.shard_map.shard_map`` where the same knob is called
-    ``check_rep``.  All model code routes through this wrapper."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def dp_axes(mesh: Mesh) -> Tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 

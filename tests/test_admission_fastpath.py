@@ -162,14 +162,19 @@ def test_fn_decisions_match_full_score_matrix():
 
 
 def test_backend_behavior_without_jax(monkeypatch):
-    """With the jitted module unavailable, "auto" silently degrades to
-    numpy (never require new deps), but an EXPLICIT "jax" request raises
-    — it must not silently measure (or CI-gate) the numpy path."""
-    monkeypatch.setattr(sched, "_ps_mod", None)
-    monkeypatch.setattr(sched, "_ps_error", ImportError("no jax"))
+    """"auto" decides batches below JAX_DECIDE_MIN on NumPy without
+    touching the jitted cascades, while an EXPLICIT "jax" request always
+    calls them and lets their failure surface — a broken jax install
+    must never be silently measured (or CI-gated) as the numpy path."""
+    class BrokenCascades:
+        def __getattr__(self, name):
+            raise RuntimeError(f"jax cascade {name} unavailable")
+
+    monkeypatch.setattr(sched, "ps", BrokenCascades())
     cp, fns = build(names=["hpc-node-cluster", "cloud-cluster"])
     plats = list(cp.platforms.values())
-    invs = [Invocation(fns["nodeinfo"], 0.0) for _ in range(80)]
+    invs = [Invocation(fns["nodeinfo"], 0.0)
+            for _ in range(sched.JAX_DECIDE_MIN - 1)]
     sched.set_score_backend("auto")
     assert cp.policy.choose_batch(invs, plats)[0] is not None
     sched.set_score_backend("jax")

@@ -65,10 +65,12 @@ def test_lru_cache_never_exceeds_capacity(items, cap):
 def test_weighted_collaboration_exact_ratio(w1, w2):
     class FakePlatform:
         def __init__(self, name):
-            self.prof = type("P", (), {"name": name,
-                                       "total_memory_mb": 1 << 20})()
+            self.prof = PlatformProfile(name=name, faas="openwhisk")
             self.failed = False
             self.deployed = {"f": object()}
+
+        def idle_warm(self, fn):
+            return 0
 
     class FakeInv:
         fn = type("F", (), {"name": "f", "memory_mb": 128})()
