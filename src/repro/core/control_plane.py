@@ -12,6 +12,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.behavioral import (EventModel, FunctionPerformanceModel,
                                    InteractionModel)
@@ -103,6 +104,10 @@ class FDNControlPlane:
         # one ``is None`` check per burst, so provenance-off admission
         # costs nothing per invocation
         self.journal = None
+        # wall-clock host spans (repro.obs.hostspans); None until
+        # attach_tracer — every tap site reads it once and guards on
+        # ``is None``
+        self.tracer = None
         # retain_completions=False drops the per-invocation completed and
         # rejected lists (open-loop sinks own the samples; 10^6-invocation
         # scenarios must not retain a million Invocation objects here)
@@ -134,6 +139,7 @@ class FDNControlPlane:
         platform.on_complete.append(self._on_complete)
         platform.on_fail.append(self._on_fail)
         platform.recorder = self.recorder
+        platform.tracer = self.tracer
         platform.telemetry = self.telemetry
         if self.qos is not None:
             platform.set_qos(self.qos)
@@ -215,6 +221,19 @@ class FDNControlPlane:
         spillover rows to their override platform *after* the main
         rows.  With no controller attached the gate costs one ``is
         None`` check.  Returns the number of admitted invocations."""
+        tr = self.tracer
+        if tr is None:
+            return self._admit(req)
+        invs = req.invs
+        if isinstance(invs, InvocationBatch):
+            rows, fns = invs.n, int(np.count_nonzero(
+                np.bincount(invs.fn_idx, minlength=len(invs.specs))))
+        else:
+            rows, fns = len(invs), len({id(inv.fn) for inv in invs})
+        with tr("fdn/admit", rows=rows, fns=fns):
+            return self._admit(req)
+
+    def _admit(self, req: AdmissionRequest) -> int:
         invs = req.invs
         columnar = isinstance(invs, InvocationBatch)
         n = invs.n if columnar else len(invs)
@@ -377,7 +396,7 @@ class FDNControlPlane:
             ov = self.platforms.get(platform_override)
             fast = [(fn, idxs, ov) for fn, idxs in groups]
         else:
-            snap = as_snapshot(alive)
+            snap = self._snapshot(alive)
             res = self.policy.fn_decisions([g[0] for g in groups], snap,
                                            n=n)
             if res is None:                 # stateful policy: full matrix
@@ -491,8 +510,12 @@ class FDNControlPlane:
                     rec.record_admit(fname, pname, now, c)
             hedge_groups.extend(hgroups.values())
 
-        for pname, group in pname_groups.items():
-            self.sidecars[pname].admit_many(group)
+        tr = self.tracer
+        if tr is None:
+            self._enqueue_objects(pname_groups)
+        else:
+            with tr("fdn/enqueue", rows=accepted):
+                self._enqueue_objects(pname_groups)
         if want_hedges:
             alt_cache: Dict[str, List[TargetPlatform]] = {}
             for target, members in hedge_groups:
@@ -558,7 +581,7 @@ class FDNControlPlane:
             ov = self.platforms.get(platform_override)
             tmap: List[Optional[TargetPlatform]] = [ov] * len(present)
         else:
-            snap = as_snapshot(self.alive_platforms())
+            snap = self._snapshot(self.alive_platforms())
             res = self.policy.fn_decisions(pres_specs, snap, n=batch.n)
             if res is None:             # stateful policy: needs real rows
                 invs = batch.to_invocations()
@@ -605,10 +628,34 @@ class FDNControlPlane:
                 group.append(idxs)
             accepted += int(idxs.size)
         self.kb.count_decisions(accepted)
+        tr = self.tracer
+        if tr is None:
+            self._enqueue_columns(batch, pname_groups)
+        else:
+            with tr("fdn/enqueue", rows=accepted):
+                self._enqueue_columns(batch, pname_groups)
+        return accepted
+
+    def _snapshot(self, alive: List[TargetPlatform]):
+        tr = self.tracer
+        if tr is None:
+            return as_snapshot(alive)
+        with tr("fdn/snapshot"):
+            return as_snapshot(alive)
+
+    def _enqueue_objects(self, pname_groups: Dict[str, List[Invocation]]):
+        """Hand each platform its admitted group: the sidecar enqueues it
+        and the platform drains once."""
+        for pname, group in pname_groups.items():
+            self.sidecars[pname].admit_many(group)
+
+    def _enqueue_columns(self, batch: InvocationBatch,
+                         pname_groups: Dict[str, List[np.ndarray]]):
+        """Columnar twin of ``_enqueue_objects``: one index group per
+        platform."""
         for pname, parts in pname_groups.items():
             idxs = parts[0] if len(parts) == 1 else np.concatenate(parts)
             self.sidecars[pname].admit_columns(batch, idxs)
-        return accepted
 
     def _admit_hedges(self, dups: List[Invocation],
                       platform: TargetPlatform):
@@ -695,6 +742,22 @@ class FDNControlPlane:
 
             self.hedge.on_duplicate.append(_hedge_span)
         return recorder
+
+    def attach_tracer(self, tracer=TraceAnnotation):
+        """Attach wall-clock host spans (repro.obs.hostspans) plane-wide:
+        admission, snapshot and enqueue here, the decision's gather,
+        dispatch and sync at the policy, drain, launch and completion at
+        every platform (current and joining later), and the event loop
+        at the clock.  ``tracer(name, **stats)`` opens a span; the
+        default, ``jax.profiler.TraceAnnotation``, records into a running
+        ``jax.profiler.trace`` on the clock of the device's ops.
+        ``attach_tracer(None)`` takes it off again."""
+        self.tracer = tracer
+        self.policy.tracer = tracer
+        self.clock.tracer = tracer
+        for p in self.platforms.values():
+            p.tracer = tracer
+        return tracer
 
     def attach_provenance(self, journal):
         """Attach a decision journal (repro.obs.provenance): every fused

@@ -150,6 +150,8 @@ class TargetPlatform:
         self.on_fail: List[Callable[[Invocation], None]] = []
         # flight recorder (repro.obs); None keeps every tap to one check
         self.recorder = None
+        # wall-clock host spans (repro.obs.hostspans); same guard
+        self.tracer = None
         # live telemetry engine (repro.obs.telemetry); same guard
         # discipline.  queued_rows mirrors the queue depth in rows (a
         # _ColumnarEntry is one deque entry but many rows) so health
@@ -465,11 +467,23 @@ class TargetPlatform:
         return self.exec_model.exec_seconds(fn, self.prof, payloads), data_t
 
     def _drain(self):
-        """Assign free/new replicas to the queue head (FIFO; stops at the
-        first invocation that cannot start), then launch every assigned
+        """Assign free/new replicas to the queue head (FIFO, or the DRR
+        plan over the class queues), then launch every assigned
         invocation in one vectorized pass."""
-        if self._cqueues is not None:
-            return self._drain_qos()
+        body = self._drain_fifo if self._cqueues is None \
+            else self._drain_qos
+        tr = self.tracer
+        if tr is None:
+            body(None)
+            return
+        with tr("fdn/drain") as span:
+            started, materialized = body(tr)
+            span.set_metadata(started=started, materialized=materialized)
+
+    def _drain_fifo(self, tr) -> Tuple[int, int]:
+        """FIFO drain: stops at the first invocation that cannot start.
+        Returns (rows started, rows materialized from columnar entries)."""
+        started = materialized = 0
         queue = self.queue
         if queue and not self.failed:
             now = self.clock.now()
@@ -513,6 +527,7 @@ class TargetPlatform:
                     # at replica-assignment time, with the bookkeeping the
                     # object path applied at enqueue
                     inv = b.materialize(i)
+                    materialized += 1
                     inv.platform = pname
                     inv.scheduled_t = entry.t
                     inv.status = "queued"
@@ -560,16 +575,19 @@ class TargetPlatform:
                         for obj in fn.data_objects:
                             self.placement.record_access(fn.name, obj,
                                                          count=count)
-                self._launch(starts, startups, colds, mem_at, exec_base,
-                             data_ts, base_busy, now)
-                self.queued_rows -= len(starts)
+                launch = self._launch if tr is None else self._launch_traced
+                launch(starts, startups, colds, mem_at, exec_base, data_ts,
+                       base_busy, now)
+                started = len(starts)
+                self.queued_rows -= started
         self._touch_energy()
         self._sample_infra()
         tel = self.telemetry
         if tel is not None:
             self.sample_health(tel)
+        return started, materialized
 
-    def _drain_qos(self):
+    def _drain_qos(self, tr) -> Tuple[int, int]:
         """DRR twin of ``_drain``: the per-start body is identical (same
         replica assignment, same hoisting, same ``_launch``), but the
         serve *order* follows a vectorized deficit-round-robin plan over
@@ -577,7 +595,9 @@ class TargetPlatform:
         (``qos.drr_plan``), deficits committed back afterwards
         (``qos.drr_commit``).  Head-of-line blocking is global: the
         first planned row that cannot start stops the drain, exactly
-        like the FIFO drain stops at its queue head."""
+        like the FIFO drain stops at its queue head.  Returns what
+        ``_drain_fifo`` returns."""
+        started = materialized = 0
         cq = self._cqueues
         crows = self._crows
         total_backlog = int(crows.sum())
@@ -635,6 +655,7 @@ class TargetPlatform:
                         queue.popleft()
                     else:
                         inv = b.materialize(i)
+                        materialized += 1
                         inv.platform = pname
                         inv.scheduled_t = entry.t
                         inv.status = "queued"
@@ -685,14 +706,18 @@ class TargetPlatform:
                             for obj in fn.data_objects:
                                 self.placement.record_access(fn.name, obj,
                                                              count=count)
-                    self._launch(starts, startups, colds, mem_at,
-                                 exec_base, data_ts, base_busy, now)
-                    self.queued_rows -= len(starts)
+                    launch = self._launch if tr is None \
+                        else self._launch_traced
+                    launch(starts, startups, colds, mem_at, exec_base,
+                           data_ts, base_busy, now)
+                    started = len(starts)
+                    self.queued_rows -= started
         self._touch_energy()
         self._sample_infra()
         tel = self.telemetry
         if tel is not None:
             self.sample_health(tel)
+        return started, materialized
 
     # -------------------------------------------------------- execution ---
     def _interference_factor(self) -> float:
@@ -790,6 +815,10 @@ class TargetPlatform:
                               [s[1] for s in starts], prof.name, now,
                               startup, data_ts, fire_at, colds)
 
+    def _launch_traced(self, starts, *args):
+        with self.tracer("fdn/launch", rows=len(starts)):
+            self._launch(starts, *args)
+
     def _finish_cb(self, inv: Invocation, fn: FunctionSpec,
                    rep: Replica) -> Callable[[], None]:
         def finish():
@@ -800,18 +829,28 @@ class TargetPlatform:
                 self._push_free(rep)
             if self.failed or inv.status == "failed":
                 return
-            inv.end_t = self.clock.now()
-            inv.status = "done"
-            self.inflight.pop(inv.id, None)
-            self.metrics.record_completion(
-                inv, visible_infra=self.prof.infra_metrics_visible)
-            self.metrics.add(self.prof.name, fn.name, "replicas",
-                             inv.end_t, float(self.replica_count(fn.name)))
-            for cb in self.on_complete:
-                cb(inv)
+            tr = self.tracer
+            if tr is None:
+                self._complete(inv, fn)
+            else:
+                with tr("fdn/complete"):
+                    self._complete(inv, fn)
             self._drain()
 
         return finish
+
+    def _complete(self, inv: Invocation, fn: FunctionSpec):
+        """Completion bookkeeping: the metrics fold and the
+        ``on_complete`` callbacks (the perf model's fold, result sinks)."""
+        inv.end_t = self.clock.now()
+        inv.status = "done"
+        self.inflight.pop(inv.id, None)
+        self.metrics.record_completion(
+            inv, visible_infra=self.prof.infra_metrics_visible)
+        self.metrics.add(self.prof.name, fn.name, "replicas",
+                         inv.end_t, float(self.replica_count(fn.name)))
+        for cb in self.on_complete:
+            cb(inv)
 
     def _fail(self, inv: Invocation, reason: str):
         inv.status = "failed"

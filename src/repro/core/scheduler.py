@@ -353,6 +353,9 @@ class Policy:
     # matching the policy constructor; ``cascade_params`` extracts the
     # live instance's values.
     CASCADE_PARAMS: Dict[str, float] = {}
+    # wall-clock host spans (repro.obs.hostspans); set by the control
+    # plane's attach_tracer
+    tracer = None
 
     def cascade_params(self) -> Dict[str, float]:
         return {k: getattr(self, k) for k in type(self).CASCADE_PARAMS}
@@ -380,10 +383,21 @@ class Policy:
         being routed (backend selection under "auto").  Returns None for
         stateful policies — callers fall back to the full score matrix.
         """
+        tr = self.tracer
+        if tr is None:
+            return self._decide(fns, snap, n, None)
+        with tr("fdn/decide"):
+            return self._decide(fns, snap, n, tr)
+
+    def _decide(self, fns, snap, n, tr):
         if _use_jax_backend(len(fns) if n is None else n):
             res = self._jax_decide(fns, snap)
             if res is not None:
-                return np.asarray(res[0]), np.asarray(res[1])
+                # the blocking device-to-host copy of the choices
+                if tr is None:
+                    return np.asarray(res[0]), np.asarray(res[1])
+                with tr("fdn/decide/sync"):
+                    return np.asarray(res[0]), np.asarray(res[1])
         rows = self.fn_cost_matrix(fns, snap)
         if rows is None:
             return None
@@ -716,14 +730,31 @@ class SLOCompositePolicy(Policy):
         """ONE fused jit step from raw estimator state: snapshot
         prediction columns (EWMA/P² gates, power model), filter cascade
         and argmin all compile into a single device program — the host
-        never materializes exec/P90/energy matrices on this path."""
+        never materializes exec/P90/energy matrices on this path.  The
+        result is not waited for here (``fn_decisions`` syncs)."""
+        tr = self.tracer
+        if tr is None:
+            return self._dispatch(self._gather(fns, snap))
+        with tr("fdn/decide/gather"):
+            args = self._gather(fns, snap)
+        # the stats name the shape, so a compile inside the span can be
+        # put down to the shape that caused it
+        with tr("fdn/decide/dispatch", f=len(fns), p=snap.n):
+            return self._dispatch(args)
+
+    def _gather(self, fns, snap):
+        """The kernel's host arguments, in its parameter order."""
         base = snap.fn_matrix(fns, None, self.placement)
         est = self.perf.estimator_columns(fns, snap.profs)
         nodes, loaded_w = snap.power
-        args = (est["ewma_v"], est["ewma_n"], est["analytic_s"],
+        return (est["ewma_v"], est["ewma_n"], est["analytic_s"],
                 est["resp_h2"], est["resp_n"], base["data_s"], nodes,
                 loaded_w, base["alive"], self._unloaded(snap),
                 _slo_vector(fns), self.energy_weight)
+
+    @staticmethod
+    def _dispatch(args):
+        """Host-to-device transfer of ``args`` and the kernel's launch."""
         if ps.use_pallas():
             return ps.fused_composite_decide_pallas(*args)
         return ps.fused_composite_decide(*args)

@@ -42,6 +42,9 @@ class SimClock:
         self._t = 0.0
         self._q: List[Tuple[float, int, Callable[[], None]]] = []
         self._seq = itertools.count()
+        # wall-clock host spans (repro.obs.hostspans); None until the
+        # control plane's attach_tracer
+        self.tracer = None
 
     def now(self) -> float:
         return self._t
@@ -86,8 +89,17 @@ class SimClock:
         return True
 
     def run_until(self, t_end: float) -> None:
-        while self._q and self._q[0][0] <= t_end:
-            self.step()
+        tr = self.tracer
+        if tr is None:
+            while self._q and self._q[0][0] <= t_end:
+                self.step()
+        else:
+            with tr("fdn/advance") as span:
+                events = 0
+                while self._q and self._q[0][0] <= t_end:
+                    self.step()
+                    events += 1
+                span.set_metadata(events=events)
         self._t = max(self._t, t_end)
 
     def run(self) -> None:

@@ -24,6 +24,13 @@ runner-up margin; the journal joins to sink completions for
 predicted-vs-realized calibration and decision regret, and replays
 offline under alternate policies — same-policy replay reproduces the
 original choices byte-identically.
+
+``hostspans`` is the wall-clock half: ``cp.attach_tracer()`` inside a
+``jax.profiler.trace`` puts profiler spans (``fdn/admit``,
+``fdn/decide/dispatch``, ``fdn/drain``, ...) and their counters on the
+control plane's host work, on the clock of the device's ops, so a trace
+shows what the host was doing while the device idled.  Detached (the
+default) each tap site costs one ``is None`` check.
 """
 from repro.obs.recorder import (ADMIT, CHAIN_STAGE, COLD_START, DATA, EXEC,
                                 HEDGE, INGRESS, KIND_NAMES, LIFECYCLE,
@@ -41,6 +48,7 @@ from repro.obs.alerts import (AlertConfig, BurnRule, alerts_section,
                               evaluate_health, evaluate_slo_burn)
 from repro.obs.provenance import (DecisionJournal, decision_provenance_section,
                                   load_journal)
+from repro.obs import hostspans
 from repro.obs.whatif import (ReplayResult, WhatIfConfig, replay,
                               replay_matches, whatif_section)
 
@@ -59,4 +67,5 @@ __all__ = [
     "DecisionJournal", "decision_provenance_section", "load_journal",
     "ReplayResult", "WhatIfConfig", "replay", "replay_matches",
     "whatif_section",
+    "hostspans",
 ]
