@@ -393,7 +393,9 @@ class Policy:
         if _use_jax_backend(len(fns) if n is None else n):
             res = self._jax_decide(fns, snap)
             if res is not None:
-                # the blocking device-to-host copy of the choices
+                # the blocking device-to-host copy of the choices, where
+                # ``_jax_decide`` returned device arrays (a host no-op for
+                # the SLO composite, whose decision has copied them back)
                 if tr is None:
                     return np.asarray(res[0]), np.asarray(res[1])
                 with tr("fdn/decide/sync"):
@@ -731,15 +733,21 @@ class SLOCompositePolicy(Policy):
         prediction columns (EWMA/P² gates, power model), filter cascade
         and argmin all compile into a single device program — the host
         never materializes exec/P90/energy matrices on this path.  The
-        result is not waited for here (``fn_decisions`` syncs)."""
+        operands go to the device in one packed buffer and the choices
+        come back in one copy, both inside ``_dispatch``: the result is
+        already on the host (the opt-in Pallas path returns device
+        arrays, which ``fn_decisions`` syncs)."""
         tr = self.tracer
         if tr is None:
             return self._dispatch(self._gather(fns, snap))
         with tr("fdn/decide/gather"):
             args = self._gather(fns, snap)
         # the stats name the shape, so a compile inside the span can be
-        # put down to the shape that caused it
-        with tr("fdn/decide/dispatch", f=len(fns), p=snap.n):
+        # put down to the shape that caused it; ``bytes`` is the packed
+        # operand buffer (0 on the Pallas path, which packs nothing)
+        f, p = len(fns), snap.n
+        packed = 0 if ps.use_pallas() else 4 * ps.packed_words(f, p)
+        with tr("fdn/decide/dispatch", f=f, p=p, bytes=packed):
             return self._dispatch(args)
 
     def _gather(self, fns, snap):
@@ -754,7 +762,8 @@ class SLOCompositePolicy(Policy):
 
     @staticmethod
     def _dispatch(args):
-        """Host-to-device transfer of ``args`` and the kernel's launch."""
+        """Pack ``args``, transfer them, launch the kernel and copy the
+        choices back (the Pallas path: transfer and launch only)."""
         if ps.use_pallas():
             return ps.fused_composite_decide_pallas(*args)
         return ps.fused_composite_decide(*args)

@@ -37,6 +37,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -183,16 +184,12 @@ def composite_explain(exec_s, data_s, p90_s, energy_j, alive, unloaded,
     return choice, any_ok, kill, runner, margin, cost
 
 
-@jax.jit
-def fused_composite_decide(ewma_v, ewma_n, analytic_s, resp_h2, resp_n,
-                           data_s, nodes, loaded_w, alive, unloaded,
-                           slo_s, energy_weight):
-    """The whole admission step in ONE jit: snapshot prediction columns
-    (exec EWMA-vs-analytic gate, P90 marker-vs-bootstrap gate, energy
-    from the platform power model) are built on-device from the raw
-    columnar estimator state (``FunctionPerformanceModel
-    .estimator_columns``), then the SLOComposite filter cascade + argmin
-    runs on them — no host-side prediction matrices at all.
+def _fused_composite(ewma_v, ewma_n, analytic_s, resp_h2, resp_n, data_s,
+                     nodes, loaded_w, alive, unloaded, slo_s, energy_weight):
+    """The whole admission step from raw estimator state: snapshot
+    prediction columns (exec EWMA-vs-analytic gate, P90 marker-vs-bootstrap
+    gate, energy from the platform power model), then the SLOComposite
+    filter cascade + argmin on them.
 
     Arithmetic mirrors ``predict_matrix`` + ``composite_decide`` op for
     op (same operand association), so the only divergence from the NumPy
@@ -205,6 +202,75 @@ def fused_composite_decide(ewma_v, ewma_n, analytic_s, resp_h2, resp_n,
     feasible = _degrade(ok & (p90_s <= slo_s[:, None]), ok)
     cost = (exec_s + data_s) + energy_weight * energy_j
     return _masked_argmin(cost, feasible)
+
+
+# the (F, P) blocks of the packed buffer that hold int32 bit patterns:
+# ewma_n, resp_n and alive
+_INT_BLOCKS = (1, 4, 6)
+
+
+def packed_words(f: int, p: int) -> int:
+    """Length of ``fused_composite_decide``'s packed operand buffer in
+    32-bit words: seven (F, P) blocks in parameter order (ewma_v, ewma_n,
+    analytic_s, resp_h2, resp_n, data_s, alive), then nodes, loaded_w and
+    unloaded (P each), the (F,) SLOs and the energy weight."""
+    return 7 * f * p + 3 * p + f + 1
+
+
+def _unpack(buf, f: int, p: int):
+    """The twelve operands of ``_fused_composite``, in its order, sliced
+    out of the packed float32 buffer (layout: ``packed_words``); counts
+    and masks are read back from their int32 bit patterns."""
+    fp = f * p
+    words = jax.lax.bitcast_convert_type(buf, _INT)
+    cols = [(words if k in _INT_BLOCKS else buf)[k * fp:(k + 1) * fp]
+            .reshape(f, p) for k in range(7)]
+    *cols, alive = cols
+    at = 7 * fp
+    return (*cols, buf[at:at + p], buf[at + p:at + 2 * p], alive != 0,
+            words[at + 2 * p:at + 3 * p] != 0,
+            buf[at + 3 * p:at + 3 * p + f], buf[at + 3 * p + f])
+
+
+@functools.partial(jax.jit, static_argnames=("f", "p"))
+def _fused_composite_decide_packed(buf, *, f: int, p: int):
+    """``_fused_composite`` on the packed operand buffer.  Returns one
+    (2, F) int32 array: choice, then ok."""
+    choice, ok = _fused_composite(*_unpack(buf, f, p))
+    return jnp.stack([choice, ok.astype(_INT)])
+
+
+def fused_composite_decide(ewma_v, ewma_n, analytic_s, resp_h2, resp_n,
+                           data_s, nodes, loaded_w, alive, unloaded,
+                           slo_s, energy_weight
+                           ) -> Tuple[np.ndarray, np.ndarray]:
+    """The whole admission step in ONE device program, with one
+    host-to-device and one device-to-host transfer: the twelve host
+    operands (``FunctionPerformanceModel.estimator_columns``, the
+    snapshot's data, liveness, power and utilization columns, the SLOs
+    and the energy weight) are packed into one float32 buffer, the
+    prediction columns, filter cascade and argmin run on the device
+    (``_fused_composite``), and the (2, F) result comes back in one copy.
+
+    Floats are cast to float32 as JAX's own argument canonicalisation
+    casts them; counts and masks go in as int32 bit patterns, exact.
+    Returns host arrays: choice (F,) int32 and ok (F,) bool."""
+    f, p = np.shape(analytic_s)
+    fp = f * p
+    buf = np.empty(packed_words(f, p), np.float32)
+    words = buf.view(np.int32)
+    for k, col in enumerate((ewma_v, ewma_n, analytic_s, resp_h2, resp_n,
+                             data_s, alive)):
+        (words if k in _INT_BLOCKS else buf)[k * fp:(k + 1) * fp] = \
+            np.ravel(col)
+    at = 7 * fp
+    buf[at:at + p] = nodes
+    buf[at + p:at + 2 * p] = loaded_w
+    words[at + 2 * p:at + 3 * p] = unloaded
+    buf[at + 3 * p:at + 3 * p + f] = slo_s
+    buf[at + 3 * p + f] = energy_weight
+    out = np.asarray(_fused_composite_decide_packed(buf, f=f, p=p))
+    return out[0], out[1] != 0
 
 
 # ---------------------------------------------------------------------------

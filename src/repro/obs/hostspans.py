@@ -31,9 +31,13 @@ The spans, by site (stats in brackets).  ``fdn/complete`` and the
       fdn/snapshot        the as_snapshot call of the batch paths
       fdn/decide          Policy.fn_decisions
         fdn/decide/gather    the kernel's host arguments (SLO composite)
-        fdn/decide/dispatch  their host-to-device transfer and the
-                             kernel's launch [f, p: the kernel's shape]
-        fdn/decide/sync      the blocking copy of the choices to the host
+        fdn/decide/dispatch  packing them into one buffer, its one
+                             host-to-device transfer, the kernel's launch
+                             and the one copy of the choices back [f, p:
+                             the kernel's shape; bytes: the packed buffer]
+        fdn/decide/sync      the copy of the choices to the host where the
+                             decision returned device arrays (a host no-op
+                             on the packed path)
       fdn/enqueue         the sidecar enqueue loop [rows]
         fdn/drain         TargetPlatform._drain [started, materialized]
           fdn/launch      TargetPlatform._launch [rows]
@@ -65,7 +69,7 @@ STATS = {
     SNAPSHOT: (),
     DECIDE: (),
     GATHER: (),
-    DISPATCH: ("f", "p"),
+    DISPATCH: ("f", "p", "bytes"),
     SYNC: (),
     ENQUEUE: ("rows",),
     DRAIN: ("started", "materialized"),

@@ -16,6 +16,7 @@ from repro.core.control_plane import FDNControlPlane
 from repro.core.invocation_batch import InvocationBatch
 from repro.core.loadgen import ColumnarResultSink, attach_completion_hooks
 from repro.core.types import DeploymentSpec
+from repro.kernels import policy_score as ps
 from repro.obs import hostspans as hs
 
 
@@ -130,8 +131,8 @@ def test_once_per_call_and_counters(traced):
     assert admit[3] == {"rows": 50,
                         "fns": len(np.unique(batch.fn_idx))}
     (disp,) = [s for s in spans if s[0] == hs.DISPATCH]
-    assert disp[3] == {"f": len(np.unique(batch.fn_idx)),
-                       "p": len(cp.platforms)}
+    f, p = len(np.unique(batch.fn_idx)), len(cp.platforms)
+    assert disp[3] == {"f": f, "p": p, "bytes": 4 * ps.packed_words(f, p)}
     (enq,) = [s for s in spans if s[0] == hs.ENQUEUE]
     assert cp.rejected_count == 0 and enq[3]["rows"] == 50
     # every completion in the advance is one fdn/complete, followed by

@@ -73,10 +73,11 @@ def test_fused_composite_pallas_lowers_to_mosaic(one_chip, f, p):
 
 @pytest.mark.parametrize("f,p", SHAPES)
 def test_fused_composite_jit_compiles(one_chip, f, p):
-    compiled = ps.fused_composite_decide.lower(
-        **_cascade_args(one_chip, f, p)).compile()
-    choice, ok = compiled.out_info
-    assert choice.shape == ok.shape == (f,)
+    buf = jax.ShapeDtypeStruct((ps.packed_words(f, p),), jnp.float32,
+                               sharding=one_chip)
+    compiled = ps._fused_composite_decide_packed.lower(
+        buf, f=f, p=p).compile()
+    assert compiled.out_info.shape == (2, f)
 
 
 def test_warm_forecast_tick_compiles(one_chip):
