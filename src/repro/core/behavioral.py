@@ -239,10 +239,32 @@ def _q_add(qs: QuantileState, fi: int, pi: int, x: float, q: float) -> None:
             qs.pos[fi, pi] = (0, 1, 2, 3, 4)
             qs.want[fi, pi] = (0, 2 * q, 4 * q, 2 + 2 * q, 4)
         return
-    h = [float(v) for v in qs.heights[fi, pi]]
-    n = [int(v) for v in qs.pos[fi, pi]]
-    ns = [float(v) for v in qs.want[fi, pi]]
+    h = qs.heights[fi, pi].tolist()
+    n = qs.pos[fi, pi].tolist()
+    ns = qs.want[fi, pi].tolist()
     _p2_update(h, n, ns, q, x)
+    qs.heights[fi, pi] = h
+    qs.pos[fi, pi] = n
+    qs.want[fi, pi] = ns
+
+
+def _q_add_many(qs: QuantileState, fi: int, pi: int, xs: Sequence[float],
+                q: float) -> None:
+    """``_q_add`` of each of ``xs`` in order, bit for bit: the bootstrap
+    rows one by one, then every later row on one load of the cell's
+    markers into Python lists, stored back once."""
+    k = 0
+    while k < len(xs) and int(qs.count[fi, pi]) < 5:
+        _q_add(qs, fi, pi, xs[k], q)
+        k += 1
+    if k == len(xs):
+        return
+    h = qs.heights[fi, pi].tolist()
+    n = qs.pos[fi, pi].tolist()
+    ns = qs.want[fi, pi].tolist()
+    for x in xs[k:]:
+        _p2_update(h, n, ns, q, x)
+    qs.count[fi, pi] += len(xs) - k
     qs.heights[fi, pi] = h
     qs.pos[fi, pi] = n
     qs.want[fi, pi] = ns
@@ -414,6 +436,50 @@ class FunctionPerformanceModel:
         if inv.cold_start and inv.platform:
             self._ewma_cell_add(st.cold_v, st.cold_n, pi, inv.queue_time)
         self.version += 1
+
+    def observe_many(self, invs: Sequence[Invocation]) -> None:
+        """``observe`` of each invocation in order, bit for bit, with one
+        load and one store of each (function, platform) cell: the EWMAs
+        run row by row in Python floats with ``observe``'s expressions,
+        the P² markers through ``_q_add_many``.  Cells are disjoint, so
+        only the per-platform cold-start EWMA needs the rows' own order,
+        and it keeps it.  An overridden ``observe`` (a subclass, a
+        wrapper) is called row by row instead."""
+        if type(self).observe is not _OBSERVE:
+            for inv in invs:
+                self.observe(inv)
+            return
+        cells: Dict[Tuple[str, str], List[Invocation]] = {}
+        for inv in invs:
+            key = (inv.fn.name, inv.platform or "?")
+            rows = cells.get(key)
+            if rows is None:
+                cells[key] = [inv]
+            else:
+                rows.append(inv)
+        # rows and columns are assigned (and the arrays grown) in the
+        # order the rows first name them, as row-by-row observes would
+        idx = [self._cell(*key) for key in cells]
+        st = self._state
+        a, b = self.ALPHA, 1 - self.ALPHA
+        for (fi, pi), rows in zip(idx, cells.values()):
+            xs = [inv.exec_time for inv in rows]
+            c = int(st.exec_n[fi, pi])
+            v = float(st.exec_v[fi, pi])
+            for x in xs:
+                v = float(x) if c == 0 else a * x + b * v
+                c += 1
+            st.exec_v[fi, pi] = v
+            st.exec_n[fi, pi] = c
+            _q_add_many(st.exec_q, fi, pi, xs, self.Q)
+            _q_add_many(st.resp_q, fi, pi,
+                        [rt for inv in rows
+                         if (rt := inv.response_time) is not None], self.Q)
+        for inv in invs:
+            if inv.cold_start and inv.platform:
+                self._ewma_cell_add(st.cold_v, st.cold_n,
+                                    self._pcol[inv.platform], inv.queue_time)
+        self.version += len(invs)
 
     def fold_observations(self, fn_name: str, platform_name: str,
                           exec_s: float, resp_s: float, k: int) -> None:
@@ -598,6 +664,10 @@ class FunctionPerformanceModel:
             "predicted_exec_s": {k: round(v, 4) for k, v in lat.items()},
             "predicted_energy_j": {k: round(v, 3) for k, v in eng.items()},
         }
+
+
+# the model's own per-row fold, which ``observe_many`` folds in batches
+_OBSERVE = FunctionPerformanceModel.observe
 
 
 class DataAccessModel:

@@ -137,6 +137,8 @@ class FDNControlPlane:
         if name not in self.placement.stores:
             self.placement.add_store(name)
         platform.on_complete.append(self._on_complete)
+        platform.on_complete_many[self._on_complete] = \
+            self._on_complete_many
         platform.on_fail.append(self._on_fail)
         platform.recorder = self.recorder
         platform.tracer = self.tracer
@@ -675,6 +677,17 @@ class FDNControlPlane:
         self.completed_count += 1
         if self.retain_completions:
             self.completed.append(inv)
+
+    def _on_complete_many(self, invs: List[Invocation]):
+        """``_on_complete`` of each invocation in order (its folds touch
+        disjoint state, so each runs over the whole group in turn)."""
+        self.perf.observe_many(invs)
+        completed = self.hedge.completed
+        for inv in invs:
+            completed(inv)
+        self.completed_count += len(invs)
+        if self.retain_completions:
+            self.completed.extend(invs)
 
     def _on_fail(self, inv: Invocation):
         self.redeliverer.handle_failure(

@@ -233,6 +233,43 @@ class ColumnarResultSink:
         self._decision[i] = inv.decision
         self._n = i + 1
 
+    def record_many(self, invs: Sequence[Invocation]):
+        """``record_completion`` of each invocation in order, one slice
+        store per column; the arrays grow by the same doublings."""
+        k = len(invs)
+        if k == 0:
+            return
+        i, j = self._n, self._n + k
+        cap = self._arrival.size
+        if j > cap:
+            cap = max(cap, 1)
+            while cap < j:
+                cap *= 2
+            self._grow(cap)
+        self._arrival[i:j] = [inv.arrival_t for inv in invs]
+        self._end[i:j] = [inv.end_t if inv.end_t is not None else np.nan
+                          for inv in invs]
+        self._exec[i:j] = [inv.exec_time for inv in invs]
+        pids, fids = self._platform_ids, self._fn_ids
+        plat, fns = [], []
+        for inv in invs:
+            plat.append(pids.setdefault(inv.platform or "?", len(pids)))
+            fname = inv.fn.name
+            fid = fids.get(fname)
+            if fid is None:
+                fid = len(fids)
+                fids[fname] = fid
+                self._fn_specs[fname] = inv.fn
+            fns.append(fid)
+        self._platform[i:j] = plat
+        self._fn[i:j] = fns
+        self._cold[i:j] = [inv.cold_start for inv in invs]
+        self._inv[i:j] = [inv.id for inv in invs]
+        self._qos[i:j] = [inv.qos for inv in invs]
+        self._tenant[i:j] = [inv.tenant for inv in invs]
+        self._decision[i:j] = [inv.decision for inv in invs]
+        self._n = j
+
     @classmethod
     def from_columns(cls, arrival: np.ndarray, end: np.ndarray,
                      platforms: Sequence[str], platform_idx: np.ndarray,
@@ -267,6 +304,7 @@ class ColumnarResultSink:
         for p in control_plane.platforms.values():
             if self.record_completion not in p.on_complete:
                 p.on_complete.append(self.record_completion)
+            p.on_complete_many[self.record_completion] = self.record_many
         return self
 
     # --------------------------------------------------------- stats ---
@@ -437,6 +475,14 @@ def run_arrivals(clock: SimClock, submit_batch: Callable[[List[Invocation]],
                            batch_window_s, sink, drain_s)
 
 
+def _fire_each(fire):
+    """Batch form of a per-invocation completion hook."""
+    def fire_many(invs):
+        for inv in invs:
+            fire(inv)
+    return fire_many
+
+
 def attach_completion_hooks(control_plane) -> None:
     """Wire Invocation._on_done callbacks through the control plane.
 
@@ -453,3 +499,4 @@ def attach_completion_hooks(control_plane) -> None:
     for p in control_plane.platforms.values():
         if fire not in p.on_complete:
             p.on_complete.append(fire)
+        p.on_complete_many[fire] = _fire_each(fire)

@@ -247,6 +247,19 @@ class ColumnarWindowSeries:
 SeriesLike = Union[WindowSeries, ColumnarWindowSeries]
 
 
+def _completion_samples(inv: Invocation, visible_infra: bool
+                        ) -> List[Tuple[str, float]]:
+    """One completion's Table-1 samples, in recording order."""
+    out = [("requests", 1.0), ("response_time", inv.response_time or 0.0),
+           ("invocations", 1.0), ("exec_time", inv.exec_time)]
+    if inv.cold_start:
+        out.append(("cold_starts", 1.0))
+    out.append(("memory_mb", float(inv.fn.memory_mb)))
+    if visible_infra:
+        out.append(("disk_io", inv.fn.read_bytes + inv.fn.write_bytes))
+    return out
+
+
 class MetricsRegistry:
     """Keyed by (platform, function, metric)."""
 
@@ -273,6 +286,14 @@ class MetricsRegistry:
         # ``is None`` check per call — same discipline as the recorder.
         self.telemetry = None
 
+    @property
+    def batches_exact(self) -> bool:
+        """Whether ``add_many`` leaves what that many ``add`` calls would:
+        columnar series store each sample (sums are taken when read), and
+        no telemetry engine sees the order across keys."""
+        return self._series_cls is ColumnarWindowSeries and \
+            self.telemetry is None
+
     def _get(self, platform: str, fn: str, metric: str) -> SeriesLike:
         key = (platform, fn, metric)
         if key not in self._m:
@@ -297,16 +318,67 @@ class MetricsRegistry:
         if self.defer_completions:
             return
         p, f, t = inv.platform or "?", inv.fn.name, inv.end_t or 0.0
-        self.add(p, f, "requests", t, 1.0)
-        self.add(p, f, "response_time", t, inv.response_time or 0.0)
-        self.add(p, f, "invocations", t, 1.0)
-        self.add(p, f, "exec_time", t, inv.exec_time)
-        if inv.cold_start:
-            self.add(p, f, "cold_starts", t, 1.0)
-        self.add(p, f, "memory_mb", t, float(inv.fn.memory_mb))
-        if visible_infra:
-            self.add(p, f, "disk_io", t,
-                     inv.fn.read_bytes + inv.fn.write_bytes)
+        for metric, v in _completion_samples(inv, visible_infra):
+            self.add(p, f, metric, t, v)
+
+    def record_completion_many(self, invs: List[Invocation],
+                               visible_infra: bool = True,
+                               extra: Tuple[Tuple[str, List[float]], ...]
+                               = ()):
+        """``record_completion`` of each invocation in order, each followed
+        by its ``extra`` samples (``(metric, one value per invocation)``,
+        under its function), for invocations that ended at one instant on
+        one platform: one ``add_many`` per key, new keys created in the
+        order the per-row calls would first touch them.  The same series
+        where ``batches_exact``."""
+        if not invs:
+            return
+        rec = not self.defer_completions
+        p, t = invs[0].platform or "?", invs[0].end_t or 0.0
+        by_fn: Dict[str, List[int]] = {}
+        for i, inv in enumerate(invs):
+            idx = by_fn.get(inv.fn.name)
+            if idx is None:
+                by_fn[inv.fn.name] = [i]
+            else:
+                idx.append(i)
+        m = self._m
+        if any((rec and ((p, f, "memory_mb") not in m or
+                         ((p, f, "cold_starts") not in m and
+                          any(invs[i].cold_start for i in idx)))) or
+               any((p, f, metric) not in m for metric, _vs in extra)
+               for f, idx in by_fn.items()):
+            # a key is new: create every key where the rows first touch it
+            for i, inv in enumerate(invs):
+                f = inv.fn.name
+                if rec:
+                    for metric, _v in _completion_samples(inv,
+                                                          visible_infra):
+                        self._get(p, f, metric)
+                for metric, _vs in extra:
+                    self._get(p, f, metric)
+        add = self.add_many
+        for f, idx in by_fn.items():
+            rows = [invs[i] for i in idx]
+            ts = np.full(len(rows), t)
+            if rec:
+                ones = np.ones(len(rows))
+                add(p, f, "requests", ts, ones)
+                add(p, f, "response_time", ts,
+                    [inv.response_time or 0.0 for inv in rows])
+                add(p, f, "invocations", ts, ones)
+                add(p, f, "exec_time", ts, [inv.exec_time for inv in rows])
+                cold = sum(1 for inv in rows if inv.cold_start)
+                if cold:
+                    add(p, f, "cold_starts", ts[:cold], ones[:cold])
+                add(p, f, "memory_mb", ts,
+                    [float(inv.fn.memory_mb) for inv in rows])
+                if visible_infra:
+                    add(p, f, "disk_io", ts,
+                        [inv.fn.read_bytes + inv.fn.write_bytes
+                         for inv in rows])
+            for metric, vs in extra:
+                add(p, f, metric, ts, [vs[i] for i in idx])
 
     def record_completions(self, sink,
                            visible_infra: Union[bool, Dict[str, bool]]

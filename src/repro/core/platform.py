@@ -25,11 +25,18 @@ NumPy array ops, with per-function costs (data-access seconds, analytic
 execution estimate) hoisted out of the per-invocation path.  A drained
 burst therefore makes one vectorized placement pass instead of N scalar
 ``_start`` calls, while producing bit-identical invocation timings.
+
+Completions are grouped the same way: the rows of one burst that end at
+one instant are one clock event, folded columnar (metrics, perf model,
+sink) where nothing watches between two of them, their drains' starts
+launched as one burst (``_finish_group``), with the same results bit for
+bit.
 """
 from __future__ import annotations
 
 import time as wall_time
 from collections import defaultdict, deque
+from functools import partial
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -58,6 +65,27 @@ class _ColumnarEntry:
         self.idxs = idxs
         self.pos = 0
         self.t = t
+
+
+class _Burst:
+    """The rows one drain starts (or every drain of one folded group of
+    completions at one instant), each with what its start observed: the
+    busy count including itself and the replica memory, for ``_launch``.
+    ``costs`` holds each function's start costs, computed once per burst
+    while they are pure (``id(fn) -> [exec, data, fn, starts]``)."""
+
+    __slots__ = ("starts", "startups", "colds", "mem_at", "busy_at",
+                 "exec_base", "data_ts", "costs")
+
+    def __init__(self):
+        self.costs: Dict[int, list] = {}
+        self.starts: List[Tuple[Invocation, FunctionSpec, Replica]] = []
+        self.startups: List[float] = []
+        self.colds: List[bool] = []
+        self.mem_at: List[float] = []
+        self.busy_at: List[int] = []
+        self.exec_base: List[float] = []
+        self.data_ts: List[float] = []
 
 
 class Replica:
@@ -147,6 +175,13 @@ class TargetPlatform:
         self.bg_cpu = 0.0                  # §5.1.2 interference knobs
         self.bg_mem = 0.0
         self.on_complete: List[Callable[[Invocation], None]] = []
+        # batch forms of on_complete callbacks, keyed by the callback:
+        # ``many(invs)`` must equal ``cb(inv)`` for each inv in order, and
+        # neither touch what a drain reads nor schedule on the clock (a
+        # fold runs it after its rows' drains).  A group of completions
+        # folds only when every callback has one (see _finish_group)
+        self.on_complete_many: Dict[Callable, Callable[[List[Invocation]],
+                                                       None]] = {}
         self.on_fail: List[Callable[[Invocation], None]] = []
         # flight recorder (repro.obs); None keeps every tap to one check
         self.recorder = None
@@ -269,16 +304,22 @@ class TargetPlatform:
         return len(self.replicas[fn])
 
     def cpu_util(self) -> float:
+        return self._cpu_util_at(self._busy)
+
+    def _cpu_util_at(self, busy: int) -> float:
         cap = max(self.prof.total_replicas, 1)
-        return min(1.0, self.bg_cpu + self.busy_replicas() / cap)
+        return min(1.0, self.bg_cpu + busy / cap)
 
     def mem_used_mb(self) -> float:
         return self._mem_replicas_mb + \
             self.bg_mem * self.prof.total_memory_mb
 
     def mem_util(self) -> float:
-        return min(1.5, self.mem_used_mb() / max(self.prof.total_memory_mb,
-                                                 1))
+        return self._mem_util_at(self._mem_replicas_mb)
+
+    def _mem_util_at(self, replicas_mb: float) -> float:
+        return min(1.5, (replicas_mb + self.bg_mem * self.prof.total_memory_mb)
+                   / max(self.prof.total_memory_mb, 1))
 
     def _touch_energy(self):
         self.energy.update(self.prof.name, self.clock.now(), self.cpu_util(),
@@ -292,6 +333,17 @@ class TargetPlatform:
                          self.cpu_util())
         self.metrics.add(self.prof.name, "_infra", "mem_util", t,
                          self.mem_util())
+
+    def _sample_infra_many(self, busy: List[int], replicas_mb: List[float]):
+        """``_sample_infra`` after each of several drains at this instant,
+        from the busy count and replica memory each drain left."""
+        if not self.prof.infra_metrics_visible:
+            return
+        ts = np.full(len(busy), self.clock.now())
+        self.metrics.add_many(self.prof.name, "_infra", "cpu_util", ts,
+                              [self._cpu_util_at(b) for b in busy])
+        self.metrics.add_many(self.prof.name, "_infra", "mem_util", ts,
+                              [self._mem_util_at(m) for m in replicas_mb])
 
     # ------------------------------------------------------- scheduling ---
     def can_start_replica(self, fn: FunctionSpec) -> bool:
@@ -484,101 +536,15 @@ class TargetPlatform:
         """FIFO drain: stops at the first invocation that cannot start.
         Returns (rows started, rows materialized from columnar entries)."""
         started = materialized = 0
-        queue = self.queue
-        if queue and not self.failed:
+        if self.queue and not self.failed:
             now = self.clock.now()
-            prof = self.prof
-            base_busy = self._busy
-            starts: List[Tuple[Invocation, FunctionSpec, Replica]] = []
-            startups: List[float] = []
-            colds: List[bool] = []
-            mem_at: List[float] = []
-            exec_base: List[float] = []
-            data_ts: List[float] = []
-            # per-fn hoisting is only sound while access costs are pure;
-            # with the LRU data cache enabled every access mutates cache
-            # state, so costs are evaluated per invocation in FIFO order
-            hoist = self.placement is None or not self.placement.cache_enabled
-            fn_cache: Dict[int, list] = {}   # id(fn) -> [exec, data, fn, n]
-            pname = prof.name
-            while queue:
-                head = queue[0]
-                entry = head if type(head) is _ColumnarEntry else None
-                if entry is not None:
-                    b = entry.batch
-                    i = int(entry.idxs[entry.pos])
-                    fn = b.specs[b.fn_idx[i]]
-                else:
-                    fn = head.fn
-                rep = self._find_replica(fn.name)
-                if rep is None:
-                    if not self.can_start_replica(fn):
-                        break
-                    rep = Replica(fn.name, COLD)
-                    self.replicas[fn.name].append(rep)
-                    spec = self.deployed.get(fn.name)
-                    if spec is not None:
-                        self._mem_replicas_mb += spec.memory_mb
-                if entry is None:
-                    inv = head
-                    queue.popleft()
-                else:
-                    # lazy materialization: the Invocation object is born
-                    # at replica-assignment time, with the bookkeeping the
-                    # object path applied at enqueue
-                    inv = b.materialize(i)
-                    materialized += 1
-                    inv.platform = pname
-                    inv.scheduled_t = entry.t
-                    inv.status = "queued"
-                    self.inflight[inv.id] = inv
-                    entry.pos += 1
-                    if entry.pos == entry.idxs.size:
-                        queue.popleft()
-                state = rep.state
-                if state == COLD:
-                    startups.append(prof.cold_start_s)
-                    colds.append(True)
-                elif state == PREWARM:
-                    # a prewarmed container pays only its attach cost and
-                    # does NOT count as a cold start — avoiding the cold
-                    # flag is exactly what prewarming buys (§6.1)
-                    startups.append(prof.cold_start_s * 0.15)
-                    colds.append(False)
-                else:
-                    startups.append(0.0)
-                    colds.append(False)
-                rep.state = WARM
-                rep.busy = True
-                rep.last_used = now
-                self._busy += 1
-                mem_at.append(self._mem_replicas_mb)
-                if hoist:
-                    cached = fn_cache.get(id(fn))
-                    if cached is None:
-                        e, d = self._fn_start_cost(fn)
-                        cached = [e, d, fn, 0]
-                        fn_cache[id(fn)] = cached
-                    cached[3] += 1
-                    e, d = cached[0], cached[1]
-                else:
-                    e, d = self._fn_start_cost(fn)
-                    if self.placement is not None:
-                        for obj in fn.data_objects:
-                            self.placement.record_access(fn.name, obj)
-                exec_base.append(e)
-                data_ts.append(d)
-                starts.append((inv, fn, rep))
-            if starts:
-                if hoist and self.placement is not None:
-                    for _e, _d, fn, count in fn_cache.values():
-                        for obj in fn.data_objects:
-                            self.placement.record_access(fn.name, obj,
-                                                         count=count)
+            burst = _Burst()
+            materialized = self._fifo_starts(burst, now)
+            self._record_accesses(burst)
+            if burst.starts:
                 launch = self._launch if tr is None else self._launch_traced
-                launch(starts, startups, colds, mem_at, exec_base, data_ts,
-                       base_busy, now)
-                started = len(starts)
+                launch(burst, now)
+                started = len(burst.starts)
                 self.queued_rows -= started
         self._touch_energy()
         self._sample_infra()
@@ -586,6 +552,105 @@ class TargetPlatform:
         if tel is not None:
             self.sample_health(tel)
         return started, materialized
+
+    def _fifo_starts(self, burst: _Burst, now: float) -> int:
+        """Assign replicas to the FIFO queue head until one cannot start,
+        appending each start to ``burst``.  Returns the rows materialized
+        from columnar entries."""
+        materialized = 0
+        queue = self.queue
+        prof = self.prof
+        starts = burst.starts
+        startups = burst.startups
+        colds = burst.colds
+        mem_at = burst.mem_at
+        busy_at = burst.busy_at
+        exec_base = burst.exec_base
+        data_ts = burst.data_ts
+        # per-fn hoisting is only sound while access costs are pure;
+        # with the LRU data cache enabled every access mutates cache
+        # state, so costs are evaluated per invocation in FIFO order
+        hoist = self.placement is None or not self.placement.cache_enabled
+        fn_cache = burst.costs
+        pname = prof.name
+        while queue:
+            head = queue[0]
+            entry = head if type(head) is _ColumnarEntry else None
+            if entry is not None:
+                b = entry.batch
+                i = int(entry.idxs[entry.pos])
+                fn = b.specs[b.fn_idx[i]]
+            else:
+                fn = head.fn
+            rep = self._find_replica(fn.name)
+            if rep is None:
+                if not self.can_start_replica(fn):
+                    break
+                rep = Replica(fn.name, COLD)
+                self.replicas[fn.name].append(rep)
+                spec = self.deployed.get(fn.name)
+                if spec is not None:
+                    self._mem_replicas_mb += spec.memory_mb
+            if entry is None:
+                inv = head
+                queue.popleft()
+            else:
+                # lazy materialization: the Invocation object is born
+                # at replica-assignment time, with the bookkeeping the
+                # object path applied at enqueue
+                inv = b.materialize(i)
+                materialized += 1
+                inv.platform = pname
+                inv.scheduled_t = entry.t
+                inv.status = "queued"
+                self.inflight[inv.id] = inv
+                entry.pos += 1
+                if entry.pos == entry.idxs.size:
+                    queue.popleft()
+            state = rep.state
+            if state == COLD:
+                startups.append(prof.cold_start_s)
+                colds.append(True)
+            elif state == PREWARM:
+                # a prewarmed container pays only its attach cost and
+                # does NOT count as a cold start — avoiding the cold
+                # flag is exactly what prewarming buys (§6.1)
+                startups.append(prof.cold_start_s * 0.15)
+                colds.append(False)
+            else:
+                startups.append(0.0)
+                colds.append(False)
+            rep.state = WARM
+            rep.busy = True
+            rep.last_used = now
+            self._busy += 1
+            busy_at.append(self._busy)
+            mem_at.append(self._mem_replicas_mb)
+            if hoist:
+                cached = fn_cache.get(id(fn))
+                if cached is None:
+                    e, d = self._fn_start_cost(fn)
+                    cached = [e, d, fn, 0]
+                    fn_cache[id(fn)] = cached
+                cached[3] += 1
+                e, d = cached[0], cached[1]
+            else:
+                e, d = self._fn_start_cost(fn)
+                if self.placement is not None:
+                    for obj in fn.data_objects:
+                        self.placement.record_access(fn.name, obj)
+            exec_base.append(e)
+            data_ts.append(d)
+            starts.append((inv, fn, rep))
+        return materialized
+
+    def _record_accesses(self, burst: _Burst):
+        """The data accesses of a burst's hoisted starts, one call per
+        (function, object) in first-start order."""
+        if self.placement is not None:
+            for _e, _d, fn, count in burst.costs.values():
+                for obj in fn.data_objects:
+                    self.placement.record_access(fn.name, obj, count=count)
 
     def _drain_qos(self, tr) -> Tuple[int, int]:
         """DRR twin of ``_drain``: the per-start body is identical (same
@@ -616,16 +681,17 @@ class TargetPlatform:
             if cap > 0:
                 plan_cls, plan_rounds = qos_mod.drr_plan(
                     crows, self._deficit, self._weights, cap)
-                base_busy = self._busy
-                starts: List[Tuple[Invocation, FunctionSpec, Replica]] = []
-                startups: List[float] = []
-                colds: List[bool] = []
-                mem_at: List[float] = []
-                exec_base: List[float] = []
-                data_ts: List[float] = []
+                burst = _Burst()
+                starts = burst.starts
+                startups = burst.startups
+                colds = burst.colds
+                mem_at = burst.mem_at
+                busy_at = burst.busy_at
+                exec_base = burst.exec_base
+                data_ts = burst.data_ts
                 hoist = self.placement is None or \
                     not self.placement.cache_enabled
-                fn_cache: Dict[int, list] = {}
+                fn_cache = burst.costs
                 pname = prof.name
                 served = [0] * qos_mod.N_QOS
                 plan_len = int(plan_cls.size)
@@ -677,6 +743,7 @@ class TargetPlatform:
                     rep.busy = True
                     rep.last_used = now
                     self._busy += 1
+                    busy_at.append(self._busy)
                     mem_at.append(self._mem_replicas_mb)
                     if hoist:
                         cached = fn_cache.get(id(fn))
@@ -701,15 +768,10 @@ class TargetPlatform:
                     plan_cls, plan_rounds, p)
                 crows -= np.asarray(served, np.int64)
                 if starts:
-                    if hoist and self.placement is not None:
-                        for _e, _d, fn, count in fn_cache.values():
-                            for obj in fn.data_objects:
-                                self.placement.record_access(fn.name, obj,
-                                                             count=count)
+                    self._record_accesses(burst)
                     launch = self._launch if tr is None \
                         else self._launch_traced
-                    launch(starts, startups, colds, mem_at, exec_base,
-                           data_ts, base_busy, now)
+                    launch(burst, now)
                     started = len(starts)
                     self.queued_rows -= started
         self._touch_energy()
@@ -720,20 +782,25 @@ class TargetPlatform:
         return started, materialized
 
     # -------------------------------------------------------- execution ---
-    def _interference_factor(self) -> float:
-        """Instantaneous CPU + memory interference — the scalar form of
+    def _interference_factor(self, busy: Optional[int] = None,
+                             replicas_mb: Optional[float] = None) -> float:
+        """CPU + memory interference at a busy count and replica memory
+        (the platform's current ones by default) — the scalar form of
         the per-burst vectors in ``_launch`` (see its docstring).  The
         two MUST stay formula-identical: the n == 1 drain fast path uses
         this, larger bursts the vectorized copy."""
         total = max(self.prof.total_replicas, 1)
         free_cores = (1.0 - self.bg_cpu) * total
-        factor = 1.0 if self.busy_replicas() <= free_cores + 1e-9 else 2.0
-        if self.mem_util() > 1.0 + 1e-6:                # swap cliff
+        if busy is None:
+            busy = self._busy
+        if replicas_mb is None:
+            replicas_mb = self._mem_replicas_mb
+        factor = 1.0 if busy <= free_cores + 1e-9 else 2.0
+        if self._mem_util_at(replicas_mb) > 1.0 + 1e-6:    # swap cliff
             factor *= 7.0
         return factor
 
-    def _launch(self, starts, startups, colds, mem_at, exec_base, data_ts,
-                base_busy: int, now: float):
+    def _launch(self, burst: _Burst, now: float):
         """Vectorized ``_start``: one pass of array math for the whole
         drained burst (paper §5.1.2, Figs. 8-9 interference semantics).
 
@@ -742,7 +809,7 @@ class TargetPlatform:
         no slowdown (paper: +50% load -> no effect).  Once they spill onto
         bg-occupied cores the OS time-shares 1:1 -> ~2x (paper: +100% load
         -> ~2x P90).  The busy count each start observes is the running
-        total *including itself* (``base_busy + 1 + i``), exactly like the
+        total *including itself* (``burst.busy_at``), exactly like the
         sequential loop this replaces.
 
         Memory: swap thrash is a cliff — as soon as demand (including
@@ -754,14 +821,13 @@ class TargetPlatform:
         invoker contend for the same cores and memory as the function).
         """
         prof = self.prof
+        starts, startups, colds = burst.starts, burst.startups, burst.colds
+        exec_base, data_ts = burst.exec_base, burst.data_ts
         n = len(starts)
-        total = max(prof.total_replicas, 1)
-        free_cores = (1.0 - self.bg_cpu) * total
         if n == 1:                     # scalar drain (closed-loop path):
             inv, fn, rep = starts[0]   # same formulas, no array overhead
-            # a single start observes exactly the platform's current
-            # state (busy == base_busy + 1, memory == mem_at[0])
-            factor = self._interference_factor()
+            factor = self._interference_factor(burst.busy_at[0],
+                                               burst.mem_at[0])
             exec_time = (exec_base[0] + prof.overhead_s) * factor \
                 + data_ts[0]
             st = now + startups[0]
@@ -773,7 +839,7 @@ class TargetPlatform:
             if colds[0]:
                 inv.cold_start = True
             self.clock.schedule(now + (startups[0] + exec_time),
-                                self._finish_cb(inv, fn, rep))
+                                partial(self._finish, inv, fn, rep))
             rec = self.recorder
             if rec is not None:
                 # fire expression repeated verbatim: the recorded EXEC end
@@ -783,10 +849,12 @@ class TargetPlatform:
                                   (now + (startups[0] + exec_time),),
                                   (colds[0],))
             return
-        busy_at = base_busy + 1 + np.arange(n)
-        factor = np.where(busy_at <= free_cores + 1e-9, 1.0, 2.0)
+        free_cores = (1.0 - self.bg_cpu) * max(prof.total_replicas, 1)
+        factor = np.where(np.asarray(burst.busy_at) <= free_cores + 1e-9,
+                          1.0, 2.0)
         pressure = np.minimum(
-            1.5, (np.asarray(mem_at) + self.bg_mem * prof.total_memory_mb)
+            1.5, (np.asarray(burst.mem_at) +
+                  self.bg_mem * prof.total_memory_mb)
             / max(prof.total_memory_mb, 1))
         factor = np.where(pressure > 1.0 + 1e-6, factor * 7.0, factor)
 
@@ -797,8 +865,13 @@ class TargetPlatform:
 
         start_l = (now + startup).tolist()
         exec_l = exec_times.tolist()
-        cbs: List[Callable[[], None]] = []
-        for i, (inv, fn, rep) in enumerate(starts):
+        fire_l = fire_at.tolist()
+        # the rows that end at one instant make one clock event: the
+        # burst's rows get consecutive sequence numbers, so nothing else
+        # can fire between two of them that share an instant
+        groups: Dict[float, list] = {}
+        for i, row in enumerate(starts):
+            inv = row[0]
             st = start_l[i]
             inv.status = "running"
             inv.start_t = st
@@ -807,37 +880,132 @@ class TargetPlatform:
             inv.data_time = data_ts[i]
             if colds[i]:
                 inv.cold_start = True
-            cbs.append(self._finish_cb(inv, fn, rep))
-        self.clock.schedule_many(fire_at.tolist(), cbs)
+            g = groups.get(fire_l[i])
+            if g is None:
+                groups[fire_l[i]] = [row]
+            else:
+                g.append(row)
+        self.clock.schedule_many(
+            list(groups), [partial(self._finish, *g[0]) if len(g) == 1
+                           else partial(self._finish_group, g)
+                           for g in groups.values()])
         rec = self.recorder
         if rec is not None:
             rec.record_launch([s[0] for s in starts],
                               [s[1] for s in starts], prof.name, now,
                               startup, data_ts, fire_at, colds)
 
-    def _launch_traced(self, starts, *args):
-        with self.tracer("fdn/launch", rows=len(starts)):
-            self._launch(starts, *args)
+    def _launch_traced(self, burst: _Burst, now: float):
+        with self.tracer("fdn/launch", rows=len(burst.starts)):
+            self._launch(burst, now)
 
-    def _finish_cb(self, inv: Invocation, fn: FunctionSpec,
-                   rep: Replica) -> Callable[[], None]:
-        def finish():
+    def _finish(self, inv: Invocation, fn: FunctionSpec, rep: Replica):
+        """One row's end: release its replica, complete it, drain."""
+        rep.busy = False
+        rep.last_used = self.clock.now()
+        if not rep.retired:
+            self._busy -= 1
+            self._push_free(rep)
+        if self.failed or inv.status == "failed":
+            return
+        tr = self.tracer
+        if tr is None:
+            self._complete(inv, fn)
+        else:
+            with tr("fdn/complete", rows=1, grouped=0):
+                self._complete(inv, fn)
+        self._drain()
+
+    def _finish_group(self, rows: List[Tuple[Invocation, FunctionSpec,
+                                             Replica]]):
+        """The end of every row of one burst that ends at this instant.
+
+        Where nothing watches between two of the rows' completions — the
+        platform is up, no telemetry, per-row hook or flight recorder
+        over a backlog, metrics that batch exactly, and every
+        ``on_complete`` callback has a batch form — the group folds
+        (``_fold_group``) to the state the rows' own ``_finish`` calls
+        leave, bit for bit.  Otherwise each row runs ``_finish`` in
+        order."""
+        metrics = self.metrics
+        many = None
+        if not (self.failed or self.telemetry is not None or
+                not metrics.batches_exact or
+                (self.queued_rows and (self._cqueues is not None or
+                                       self.recorder is not None)) or
+                any(inv.status == "failed" or inv._on_done is not None
+                    for inv, _fn, _rep in rows)):
+            forms = self.on_complete_many
+            many = [forms.get(cb) for cb in self.on_complete]
+            if None in many:
+                many = None
+        if many is None:
+            for row in rows:
+                self._finish(*row)
+            return
+        tr = self.tracer
+        if tr is None:
+            self._fold_group(rows, many)
+        else:
+            with tr("fdn/complete", rows=len(rows), grouped=len(rows)):
+                self._fold_group(rows, many)
+
+    def _fold_group(self, rows, many):
+        """``_finish`` of each row in order, folded.  Row by row: its
+        replica is released and, while the FIFO queue holds rows, the
+        drain its completion triggers assigns replicas, seeing only the
+        replicas freed so far.  Then the rows those drains started are
+        launched as one burst (its instants in the order the drains
+        would have scheduled them), the completions are recorded with
+        one ``add_many`` per metrics key, each callback's batch form runs
+        once, the drains' infra samples are added, and the energy meter
+        is updated once (updates at one instant integrate no time, so
+        the last utilization alone counts).  The completions and the
+        drains touch disjoint state, so only their order within each
+        kind has to be kept."""
+        now = self.clock.now()
+        inflight = self.inflight
+        push_free = self._push_free
+        replicas = self.replicas
+        queue = self.queue if self._cqueues is None else None
+        burst = None
+        invs: List[Invocation] = []
+        n_replicas: List[float] = []   # each row's ``replicas`` sample
+        busy: List[int] = []           # what each row's drain left
+        replicas_mb: List[float] = []
+        for inv, fn, rep in rows:
             rep.busy = False
-            rep.last_used = self.clock.now()
+            rep.last_used = now
             if not rep.retired:
                 self._busy -= 1
-                self._push_free(rep)
-            if self.failed or inv.status == "failed":
-                return
-            tr = self.tracer
-            if tr is None:
-                self._complete(inv, fn)
-            else:
-                with tr("fdn/complete"):
-                    self._complete(inv, fn)
-            self._drain()
-
-        return finish
+                push_free(rep)
+            inv.end_t = now
+            inv.status = "done"
+            inflight.pop(inv.id, None)
+            invs.append(inv)
+            n_replicas.append(float(len(replicas[fn.name])))
+            if queue:
+                if burst is None:
+                    burst = _Burst()
+                self._fifo_starts(burst, now)
+            busy.append(self._busy)
+            replicas_mb.append(self._mem_replicas_mb)
+        if burst is not None and burst.starts:
+            # the drains' hoisted start costs are one instant's, so they
+            # hold across the drains, whose accesses are summed per key
+            self._record_accesses(burst)
+            launch = self._launch if self.tracer is None \
+                else self._launch_traced
+            launch(burst, now)
+            self.queued_rows -= len(burst.starts)
+        self.metrics.record_completion_many(
+            invs, visible_infra=self.prof.infra_metrics_visible,
+            extra=(("replicas", n_replicas),))
+        for cb_many in many:
+            cb_many(invs)
+        # the infra keys exist: the drain that launched these rows sampled
+        self._sample_infra_many(busy, replicas_mb)
+        self._touch_energy()
 
     def _complete(self, inv: Invocation, fn: FunctionSpec):
         """Completion bookkeeping: the metrics fold and the

@@ -42,8 +42,13 @@ The spans, by site (stats in brackets).  ``fdn/complete`` and the
         fdn/drain         TargetPlatform._drain [started, materialized]
           fdn/launch      TargetPlatform._launch [rows]
     fdn/advance           SimClock.run_until [events: heap entries popped]
-      fdn/complete        completion bookkeeping: metrics fold, callbacks
-      fdn/drain           the drain each completion triggers
+      fdn/complete        completion bookkeeping: metrics fold, callbacks;
+                          one row, or every row a burst ends at one instant
+                          folded as a group, with the drains its rows
+                          trigger [rows: rows completed; grouped: rows that
+                          took the group's fold]
+        fdn/launch        the rows a folded group's drains start [rows]
+      fdn/drain           the drain each row completed alone triggers
 
 The tap sites in ``repro.core`` spell the names out, since ``repro.core``
 cannot import ``repro.obs`` (which imports it); ``tests/test_hostspans.py``
@@ -74,6 +79,6 @@ STATS = {
     ENQUEUE: ("rows",),
     DRAIN: ("started", "materialized"),
     LAUNCH: ("rows",),
-    COMPLETE: (),
+    COMPLETE: ("rows", "grouped"),
     ADVANCE: ("events",),
 }

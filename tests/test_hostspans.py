@@ -112,7 +112,8 @@ def test_spans_nest_as_documented(traced):
         hs.ADMIT: {None}, hs.SNAPSHOT: {hs.ADMIT}, hs.DECIDE: {hs.ADMIT},
         hs.GATHER: {hs.DECIDE}, hs.DISPATCH: {hs.DECIDE},
         hs.SYNC: {hs.DECIDE}, hs.ENQUEUE: {hs.ADMIT},
-        hs.DRAIN: {hs.ENQUEUE, hs.ADVANCE}, hs.LAUNCH: {hs.DRAIN},
+        hs.DRAIN: {hs.ENQUEUE, hs.ADVANCE},
+        hs.LAUNCH: {hs.DRAIN, hs.COMPLETE},
         hs.COMPLETE: {hs.ADVANCE}, hs.ADVANCE: {None}}
     for (name, *_), par in zip(spans, parent):
         assert par in allowed[name], (name, par)
@@ -135,13 +136,22 @@ def test_once_per_call_and_counters(traced):
     assert disp[3] == {"f": f, "p": p, "bytes": 4 * ps.packed_words(f, p)}
     (enq,) = [s for s in spans if s[0] == hs.ENQUEUE]
     assert cp.rejected_count == 0 and enq[3]["rows"] == 50
-    # every completion in the advance is one fdn/complete, followed by
-    # its own drain; every row the run started was launched
+    # every row completes in the advance, in one fdn/complete per clock
+    # event: a group folded whole (rows == grouped > 1) or one row
+    # (rows 1, grouped 0), followed by its own drain; every row the run
+    # started was launched
     (adv,) = [s for s in spans if s[0] == hs.ADVANCE]
-    assert count[hs.COMPLETE] >= 50
+    completes = [s[3] for s in spans if s[0] == hs.COMPLETE]
+    assert sum(c["rows"] for c in completes) == 50
     assert adv[3]["events"] >= count[hs.COMPLETE]
+    grouped = [c for c in completes if c["grouped"]]
+    single = [c for c in completes if not c["grouped"]]
+    assert grouped and single
+    assert all(c["rows"] == c["grouped"] > 1 for c in grouped)
+    assert all(c["rows"] == 1 for c in single)
     drains = [s for s, p in zip(spans, parent) if s[0] == hs.DRAIN]
-    assert len(drains) >= count[hs.COMPLETE]
+    assert sum(1 for s, p in zip(spans, parent)
+               if s[0] == hs.DRAIN and p == hs.ADVANCE) >= len(single)
     started = sum(s[3]["started"] for s in drains)
     launched = sum(s[3]["rows"] for s in spans if s[0] == hs.LAUNCH)
     assert started == launched == 50
