@@ -15,17 +15,16 @@ decision runs the compiled cascade on the device:
      the TPU platform fleet, hedging and predictive prewarm on;
   d. the ``smoke/tiny`` and ``qos/burst-storm-drr`` scenario reports,
      diffed against ``benchmarks/golden/``;
-  e. the fused Pallas cascade compiled for the chip, equal to the jitted
-     cascade and to the NumPy ``SLOCompositePolicy`` cascade.
+  e. the packed device decision (``fused_composite_decide``) equal to
+     the NumPy ``SLOCompositePolicy`` cascade.
 
 Each phase prints one JSON line naming the device, with its counts,
 checks and wall seconds (compilation and warm-up reported apart).  A
 failed check raises and the script exits non-zero.  The last line is
 ``{"ok": true, "device": {...}}`` and appears only when every phase
 passed on a TPU.  Without a TPU the script stops before any phase.
-``--rehearse`` runs the phases on whatever device JAX has, Pallas in
-interpret mode and phase b at a tenth of its size, and never prints
-``"ok"``.
+``--rehearse`` runs the phases on whatever device JAX has, phase b at
+a tenth of its size, and never prints ``"ok"``.
 """
 from __future__ import annotations
 
@@ -39,7 +38,7 @@ ROOT = Path(__file__).resolve().parent
 sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
 
 GOLDEN_SCENARIOS = ("smoke/tiny", "qos/burst-storm-drr")
-PALLAS_SHAPES = ((4, 5), (64, 1024))      # paper fleet; pod-scale registry
+CASCADE_SHAPES = ((4, 5), (64, 1024))     # paper fleet; pod-scale registry
 SERVE_DURATION_S = 30.0
 
 
@@ -176,8 +175,8 @@ def phase_golden_reports(device):
 def _cascade_columns(f: int, p: int, seed: int):
     """Seeded estimator columns on a dyadic grid: every sum and product of
     the cascade is exact in float32 and float64, so the f32 device
-    cascades and the f64 NumPy oracle must agree bit for bit (ties
-    included, broken first-lowest by all three)."""
+    decision and the f64 NumPy oracle must agree bit for bit (ties
+    included, broken first-lowest by both)."""
     import numpy as np
     rng = np.random.default_rng(seed)
 
@@ -204,75 +203,52 @@ def _numpy_cascade(cols, params):
     """The NumPy ``SLOCompositePolicy`` cascade on the same columns."""
     import numpy as np
     from repro.core.scheduler import SLOCompositePolicy
-    exec_s = np.where(cols["ewma_n"] >= 3, cols["ewma_v"],
-                      cols["analytic_s"])
+    from repro.kernels.policy_score import masked_argmin, predict_columns
+    exec_s, p90_s, energy_j = predict_columns(
+        np, cols["ewma_v"], cols["ewma_n"], cols["analytic_s"],
+        cols["resp_h2"], cols["resp_n"], cols["nodes"], cols["loaded_w"])
     feats = {"alive": cols["alive"], "cpu_util": cols["cpu_util"],
              "mem_util": cols["mem_util"], "slo_s": cols["slo_s"],
-             "exec_s": exec_s, "data_s": cols["data_s"],
-             "p90_s": np.where(cols["resp_n"] >= 10, cols["resp_h2"],
-                               exec_s * 1.5),
-             "energy_j": (exec_s * cols["nodes"][None, :])
-             * cols["loaded_w"][None, :]}
+             "exec_s": exec_s, "data_s": cols["data_s"], "p90_s": p90_s,
+             "energy_j": energy_j}
     cost, kill = SLOCompositePolicy.cascade(feats, params)
-    masked = np.where(kill == 0, cost, np.inf)
-    finite = np.isfinite(masked)
-    return (np.argmin(np.where(finite, masked, np.inf), axis=1),
-            finite.any(axis=1))
+    return masked_argmin(np, cost, kill == 0)
 
 
-def phase_pallas_cascade(device, interpret: bool):
-    import jax
+def phase_cascade(device):
     import numpy as np
     from repro.core.scheduler import SLOCompositePolicy
     from repro.kernels import policy_score as ps
 
     params = dict(SLOCompositePolicy.CASCADE_PARAMS, energy_weight=0.125)
-    for seed, (f, p) in enumerate(PALLAS_SHAPES):
+    for seed, (f, p) in enumerate(CASCADE_SHAPES):
         cols = _cascade_columns(f, p, seed)
         unloaded = ((cols["cpu_util"] < params["cpu_threshold"])
                     & (cols["mem_util"] < params["mem_threshold"]))
-        args = [jax.device_put(np.asarray(cols[k], dtype))
-                for k, dtype in (("ewma_v", np.float32),
-                                 ("ewma_n", np.int32),
-                                 ("analytic_s", np.float32),
-                                 ("resp_h2", np.float32),
-                                 ("resp_n", np.int32),
-                                 ("data_s", np.float32),
-                                 ("nodes", np.float32),
-                                 ("loaded_w", np.float32),
-                                 ("alive", np.bool_))]
-        args += [jax.device_put(unloaded),
-                 jax.device_put(np.asarray(cols["slo_s"], np.float32)),
-                 params["energy_weight"]]
-        timings = {}
-        outs = {}
-        for name, call in (
-                ("pallas", lambda: ps.fused_composite_decide_pallas(
-                    *args, interpret=interpret)),
-                ("jit", lambda: ps.fused_composite_decide(*args))):
-            t0 = time.perf_counter()
-            jax.block_until_ready(call())
-            timings[f"{name}_first_call_s"] = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            choice, ok = jax.block_until_ready(call())
-            timings[f"{name}_call_s"] = time.perf_counter() - t0
-            outs[name] = (np.asarray(choice), np.asarray(ok))
+        args = [cols[k] for k in ("ewma_v", "ewma_n", "analytic_s",
+                                  "resp_h2", "resp_n", "data_s", "nodes",
+                                  "loaded_w", "alive")]
+        args += [unloaded, cols["slo_s"], params["energy_weight"]]
+        t0 = time.perf_counter()
+        ps.fused_composite_decide(*args)
+        first_call_s = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        choice, ok = ps.fused_composite_decide(*args)
+        call_s = time.perf_counter() - t0
         want = _numpy_cascade(cols, params)
-        for name, (choice, ok) in outs.items():
-            require(np.array_equal(ok, want[1]),
-                    f"{name} ok differs from NumPy at {f}x{p}")
-            require(np.array_equal(choice[ok], want[0][ok]),
-                    f"{name} choices differ from NumPy at {f}x{p}")
-        emit("e_pallas_cascade", device, shape=[f, p],
-             interpret=interpret, feasible_rows=int(want[1].sum()),
-             pallas_eq_jit_eq_numpy=True, **timings)
+        require(np.array_equal(ok, want[1]),
+                f"ok differs from NumPy at {f}x{p}")
+        require(np.array_equal(choice[ok], want[0][ok]),
+                f"choices differ from NumPy at {f}x{p}")
+        emit("e_cascade", device, shape=[f, p],
+             feasible_rows=int(want[1].sum()), jit_eq_numpy=True,
+             jit_first_call_s=first_call_s, jit_call_s=call_s)
 
 
 def main(argv) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--rehearse", action="store_true",
-                    help="run on any device, Pallas interpreted; never "
-                         "reports ok")
+                    help="run on any device; never reports ok")
     args = ap.parse_args(argv)
     try:
         from benchmarks.fdn_common import use_compile_cache
@@ -300,7 +276,7 @@ def main(argv) -> int:
                              4_000 if args.rehearse else 40_000)
     phase_gateway(device)
     phase_golden_reports(device)
-    phase_pallas_cascade(device, interpret=args.rehearse)
+    phase_cascade(device)
     emit("done", device, wall_s=time.perf_counter() - t0,
          compiles=compiles.n)
     if args.rehearse:

@@ -33,6 +33,7 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.types import FunctionSpec, Invocation, PlatformProfile, SLO
+from repro.kernels.policy_score import predict_columns
 
 
 class P2Quantile:
@@ -610,10 +611,7 @@ class FunctionPerformanceModel:
         ``exec_s`` (+ ``p90_s`` / ``energy_j`` on request).  Every element
         equals the corresponding scalar ``predict_*`` call bit for bit."""
         ev, en, rh, rc = self._gather(fns, profs)
-        exec_s = np.where(en >= 3, ev, self.analytic_matrix(fns, profs))
-        out = {"exec_s": exec_s}
-        if p90:
-            out["p90_s"] = np.where(rc >= 10, rh, exec_s * 1.5)
+        nodes = lw = None
         if energy:
             pk = tuple(id(p) for p in profs)
             hit = self._power_cache
@@ -623,15 +621,18 @@ class FunctionPerformanceModel:
                 nodes = np.array([float(p.nodes) for p in profs])
                 lw = np.array([p.loaded_w_per_node for p in profs])
                 self._power_cache = (pk, (nodes, lw))
-            out["energy_j"] = (exec_s * nodes[None, :]) * lw[None, :]
-        return out
+        cols = predict_columns(np, ev, en, self.analytic_matrix(fns, profs),
+                               rh if p90 else None, rc, nodes, lw)
+        return {k: v for k, v in zip(("exec_s", "p90_s", "energy_j"), cols)
+                if v is not None}
 
     def estimator_columns(self, fns: Sequence[FunctionSpec],
                           profs: Sequence[PlatformProfile]
                           ) -> Dict[str, np.ndarray]:
         """Raw gathered state for the fused jitted admission step
         (``repro.kernels.policy_score.fused_composite_decide``): the
-        device kernel applies the observation-count gates itself."""
+        device applies ``predict_matrix``'s gates (``predict_columns``)
+        itself."""
         ev, en, rh, rc = self._gather(fns, profs)
         return {"ewma_v": ev, "ewma_n": en, "resp_h2": rh, "resp_n": rc,
                 "analytic_s": self.analytic_matrix(fns, profs)}

@@ -22,12 +22,15 @@ A batch admission decision additionally collapses to one row per
 which invocation carries it): ``fn_decisions`` evaluates the filter
 cascade + cost + argmin once per (function, platform-set) and the batch
 router broadcasts the per-function choice to every invocation of that
-function.  The cascade runs on one of two backends:
+function.  Each stateless policy's cascade is written once, as an array
+function in ``repro.kernels.policy_score``; the policy gathers the
+columns it reads (``_features``) and ``Policy`` derives the rest from
+that one definition, on one of two backends:
 
-  * ``numpy`` — host arrays (the historical path and parity oracle);
-  * ``jax``   — the ``jax.jit``-compiled cascades in
-    ``repro.kernels.policy_score`` (with an optional fused Pallas
-    filter+argmin kernel for the composite policy).
+  * ``numpy`` — host arrays, float64 (the parity oracle, and the
+    ``cascade`` the decision journal and what-if replay re-run);
+  * ``jax``   — the same function under ``jax.jit``; the SLO composite
+    runs as one packed device program from the raw estimator state.
 
 ``set_score_backend("numpy"|"jax"|"auto")`` selects it; ``auto`` (the
 default) uses jax for batches of at least ``JAX_DECIDE_MIN`` invocations
@@ -42,6 +45,8 @@ identical platforms.
 """
 from __future__ import annotations
 
+import functools
+import inspect
 import itertools
 import os
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -52,7 +57,11 @@ from repro.core.behavioral import FunctionPerformanceModel
 from repro.core.data_placement import DataPlacementManager
 from repro.core.platform import TargetPlatform
 from repro.core.types import FunctionSpec, Invocation
+# device programs are reached through ``ps`` (tests and the benchmark
+# wrap them there); the host's NumPy pieces are imported by name
 from repro.kernels import policy_score as ps
+from repro.kernels.policy_score import (  # noqa: F401  (KILL_* re-exported)
+    KILL_DEAD, KILL_SLO, KILL_UTIL, kill_bits, masked_argmin)
 
 # Minimum batch size at which the "auto" backend switches to the jitted
 # cascades (below it, host NumPy wins on dispatch overhead alone).
@@ -298,19 +307,44 @@ class _SpecInv:
         self.fn = fn
 
 
-# Filter-kill bitmask bits recorded by the decision journal
-# (repro.obs.provenance).  Values mirror ``repro.kernels.policy_score``.
-KILL_DEAD = 1    # platform failed / no replicas (alive mask)
-KILL_UTIL = 2    # alive but dropped by the utilization filter
-KILL_SLO = 4     # survived utilization but dropped by SLO feasibility
-
-
 def _row(x: np.ndarray) -> np.ndarray:
     """Broadcast a per-platform (P,) vector against (F, P) matrices; a
     journal replay passes already-row-shaped (rows, P) matrices through
     unchanged — broadcasting duplicates values, so the elementwise
     arithmetic is bit-identical either way."""
     return x if x.ndim == 2 else x[None, :]
+
+
+def _unloaded(cpu_util, mem_util, cpu_threshold, mem_threshold):
+    """Platforms under both utilization thresholds (host, float64)."""
+    return (cpu_util < cpu_threshold) & (mem_util < mem_threshold)
+
+
+@functools.lru_cache(maxsize=None)
+def _operand_names(kernel) -> Tuple[str, ...]:
+    return tuple(inspect.signature(kernel).parameters)[1:]
+
+
+def _operands(kernel, feats: Dict[str, np.ndarray],
+              params: Dict[str, float]) -> list:
+    """``kernel``'s operands after ``xp``, in its parameter order, by
+    name: a ``decision_features`` column or a cascade param, or
+    ``unloaded``, compared here from the utilization columns.
+    Per-platform columns are made row-shaped."""
+    out = []
+    for name in _operand_names(kernel):
+        if name == "unloaded":
+            v = _row(_unloaded(feats["cpu_util"], feats["mem_util"],
+                               params["cpu_threshold"],
+                               params["mem_threshold"]))
+        elif name in params:
+            v = params[name]
+        elif name == "cold_start_s":
+            v = _row(feats[name])
+        else:
+            v = feats[name]
+        out.append(v)
+    return out
 
 
 def decision_features(fns: Sequence[FunctionSpec], snap: PlatformSnapshot,
@@ -341,17 +375,16 @@ def decision_features(fns: Sequence[FunctionSpec], snap: PlatformSnapshot,
 class Policy:
     name = "base"
 
-    # Stateless policies expose ``cascade``: a pure staticmethod over the
-    # ``decision_features`` columns returning (cost (F, P) float64,
-    # kill (F, P) uint8 bitmask; kill == 0 marks feasible-after-degrade).
-    # It mirrors ``fn_cost_matrix`` op for op, so re-running it over
-    # journaled feature columns reproduces the original numpy-backend
-    # choices byte-identically (the what-if correctness oracle).
-    # Stateful rotation policies keep ``cascade = None``.
-    cascade = None
-    # Tunables ``cascade`` reads from its params dict, with defaults
-    # matching the policy constructor; ``cascade_params`` extracts the
-    # live instance's values.
+    # A stateless policy's decision: its ``repro.kernels.policy_score``
+    # function over the ``decision_features`` columns it reads (operands
+    # by parameter name, ``_operands``), which ``_features`` gathers for a
+    # live decision.  ``cascade``, ``fn_cost_matrix`` and ``_jax_decide``
+    # are derived from it.  Stateful rotation policies keep
+    # ``kernel = None`` and set ``cascade = None``.
+    kernel = None
+    # Tunables the kernel reads as operands, with defaults matching the
+    # policy constructor; ``cascade_params`` extracts the live instance's
+    # values.
     CASCADE_PARAMS: Dict[str, float] = {}
     # wall-clock host spans (repro.obs.hostspans); set by the control
     # plane's attach_tracer
@@ -360,20 +393,50 @@ class Policy:
     def cascade_params(self) -> Dict[str, float]:
         return {k: getattr(self, k) for k in type(self).CASCADE_PARAMS}
 
+    def _features(self, fns: Sequence[FunctionSpec],
+                  snap: PlatformSnapshot) -> Dict[str, np.ndarray]:
+        """The ``decision_features`` columns ``kernel`` reads, for one
+        live decision."""
+        raise NotImplementedError
+
+    @classmethod
+    def _stages(cls, feats, params):
+        """The kernel under NumPy: (cost, (alive, ok, feasible))."""
+        return cls.kernel(np, *_operands(cls.kernel, feats, params))
+
+    @classmethod
+    def cascade(cls, feats: Dict[str, np.ndarray],
+                params: Dict[str, float]):
+        """The decision over ``decision_features`` columns, float64:
+        (cost (F, P), kill (F, P) uint8 bitmask; kill == 0 marks
+        feasible-after-degrade).  The live decision's own definition, so
+        re-running it over journaled feature columns reproduces the
+        original numpy-backend choices byte-identically (the what-if
+        correctness oracle)."""
+        cost, stages = cls._stages(feats, params)
+        return cost, kill_bits(np, *stages)
+
     # ------------------------------------------------- vectorized core ---
     def fn_cost_matrix(self, fns: Sequence[FunctionSpec],
                        snap: PlatformSnapshot) -> Optional[np.ndarray]:
         """(F, P) masked cost matrix, one row per distinct function
         (np.inf marks an infeasible pairing) — or None for policies whose
         score is per-invocation stateful (rotation policies)."""
-        return None
+        if self.kernel is None:
+            return None
+        cost, (*_, feasible) = self._stages(self._features(fns, snap),
+                                            self.cascade_params())
+        return np.where(feasible, cost, np.inf)
 
     def _jax_decide(self, fns: Sequence[FunctionSpec],
                     snap: PlatformSnapshot
                     ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
-        """Jitted-cascade decision (repro.kernels.policy_score), or None
-        when this policy has no compiled variant."""
-        return None
+        """The kernel's decision under ``jax.jit`` (device arrays), or
+        None for a stateful policy."""
+        if self.kernel is None:
+            return None
+        return ps.jit_decide(self.kernel, *_operands(
+            self.kernel, self._features(fns, snap), self.cascade_params()))
 
     def fn_decisions(self, fns: Sequence[FunctionSpec],
                      snap: PlatformSnapshot, n: Optional[int] = None
@@ -403,9 +466,7 @@ class Policy:
         rows = self.fn_cost_matrix(fns, snap)
         if rows is None:
             return None
-        finite = np.isfinite(rows)
-        return (np.argmin(np.where(finite, rows, np.inf), axis=1),
-                finite.any(axis=1))
+        return masked_argmin(np, rows, True)
 
     def score(self, invs: Sequence[Invocation],
               snap: PlatformSnapshot) -> np.ndarray:
@@ -441,10 +502,7 @@ class Policy:
         res = self.fn_decisions([g[0] for g in groups], snap, n=len(invs))
         plats = snap.platforms
         if res is None:
-            costs = self.score(invs, snap)
-            finite = np.isfinite(costs)
-            any_ok = finite.any(axis=1)
-            idx = np.argmin(np.where(finite, costs, np.inf), axis=1)
+            idx, any_ok = masked_argmin(np, self.score(invs, snap), True)
             return [plats[j] if ok else None
                     for j, ok in zip(idx.tolist(), any_ok.tolist())]
         idx, ok_arr = res
@@ -461,33 +519,21 @@ class Policy:
         return self.choose_batch([inv], platforms)[0]
 
 
-def _masked(cost: np.ndarray, mask: np.ndarray) -> np.ndarray:
-    return np.where(mask, cost, np.inf)
-
-
 class PerformanceRankedPolicy(Policy):
     name = "perf_ranked"
+    kernel = staticmethod(ps.perf_ranked)
 
     def __init__(self, perf: FunctionPerformanceModel):
         self.perf = perf
 
-    def fn_cost_matrix(self, fns, snap):
-        m = snap.fn_matrix(fns, self.perf)
-        return _masked(m["exec_s"], m["alive"])
-
-    def _jax_decide(self, fns, snap):
-        m = snap.fn_matrix(fns, self.perf)
-        return ps.perf_ranked_decide(m["exec_s"], m["alive"])
-
-    @staticmethod
-    def cascade(feats, params):
-        alive = feats["alive"]
-        kill = np.where(~alive, KILL_DEAD, 0).astype(np.uint8)
-        return feats["exec_s"], kill
+    def _features(self, fns, snap):
+        return snap.fn_matrix(fns, self.perf)
 
 
 class UtilizationAwarePolicy(Policy):
     name = "utilization_aware"
+    kernel = staticmethod(ps.utilization)
+    CASCADE_PARAMS = {"cpu_threshold": 0.9, "mem_threshold": 0.9}
 
     def __init__(self, perf: FunctionPerformanceModel,
                  cpu_threshold: float = 0.9, mem_threshold: float = 0.9):
@@ -495,39 +541,16 @@ class UtilizationAwarePolicy(Policy):
         self.cpu_threshold = cpu_threshold
         self.mem_threshold = mem_threshold
 
-    def _unloaded(self, snap):
-        return (snap.cpu_util < self.cpu_threshold) & \
-            (snap.mem_util < self.mem_threshold)
-
-    def fn_cost_matrix(self, fns, snap):
-        m = snap.fn_matrix(fns, self.perf)
-        ok = m["alive"] & self._unloaded(snap)[None, :]
-        ok = np.where(ok.any(axis=1, keepdims=True), ok, m["alive"])
-        return _masked(m["exec_s"], ok)
-
-    def _jax_decide(self, fns, snap):
-        m = snap.fn_matrix(fns, self.perf)
-        return ps.utilization_decide(m["exec_s"], m["alive"],
-                                     self._unloaded(snap))
-
-    CASCADE_PARAMS = {"cpu_threshold": 0.9, "mem_threshold": 0.9}
-
-    @staticmethod
-    def cascade(feats, params):
-        alive = feats["alive"]
-        unloaded = _row((feats["cpu_util"] < params["cpu_threshold"]) &
-                        (feats["mem_util"] < params["mem_threshold"]))
-        ok = alive & unloaded
-        ok = np.where(ok.any(axis=1, keepdims=True), ok, alive)
-        kill = (np.where(~alive, KILL_DEAD, 0) |
-                np.where(alive & ~ok, KILL_UTIL, 0)).astype(np.uint8)
-        return feats["exec_s"], kill
+    def _features(self, fns, snap):
+        return {**snap.fn_matrix(fns, self.perf),
+                "cpu_util": snap.cpu_util, "mem_util": snap.mem_util}
 
 
 class RoundRobinCollaboration(Policy):
     """Stateful: ``score`` consumes one rotation tick per row, so batch
     routing advances the round-robin exactly like N scalar ``choose``s."""
     name = "round_robin"
+    cascade = None
 
     def __init__(self):
         self._rr = itertools.count()
@@ -551,6 +574,7 @@ class WeightedCollaboration(Policy):
     derived from the performance model (capacity-proportional). Stateful:
     ``score`` walks the weighted schedule one row at a time."""
     name = "weighted"
+    cascade = None
 
     def __init__(self, weights: Dict[str, int]):
         self.weights = dict(weights)
@@ -598,25 +622,15 @@ class WeightedCollaboration(Policy):
 
 class DataLocalityPolicy(Policy):
     name = "data_locality"
+    kernel = staticmethod(ps.locality)
 
     def __init__(self, perf: FunctionPerformanceModel,
                  placement: DataPlacementManager):
         self.perf = perf
         self.placement = placement
 
-    def fn_cost_matrix(self, fns, snap):
-        m = snap.fn_matrix(fns, self.perf, self.placement)
-        return _masked(m["exec_s"] + m["data_s"], m["alive"])
-
-    def _jax_decide(self, fns, snap):
-        m = snap.fn_matrix(fns, self.perf, self.placement)
-        return ps.locality_decide(m["exec_s"], m["data_s"], m["alive"])
-
-    @staticmethod
-    def cascade(feats, params):
-        alive = feats["alive"]
-        kill = np.where(~alive, KILL_DEAD, 0).astype(np.uint8)
-        return feats["exec_s"] + feats["data_s"], kill
+    def _features(self, fns, snap):
+        return snap.fn_matrix(fns, self.perf, self.placement)
 
 
 class WarmAwarePolicy(Policy):
@@ -627,30 +641,16 @@ class WarmAwarePolicy(Policy):
     predictively prewarmed) already hold capacity for it."""
 
     name = "warm_aware"
+    kernel = staticmethod(ps.warm)
 
     def __init__(self, perf: FunctionPerformanceModel,
                  placement: Optional[DataPlacementManager] = None):
         self.perf = perf
         self.placement = placement
 
-    def fn_cost_matrix(self, fns, snap):
-        m = snap.fn_matrix(fns, self.perf, self.placement)
-        cold = np.where(m["warm_free"] > 0.0, 0.0,
-                        snap.cold_start_s[None, :])
-        return _masked(m["exec_s"] + m["data_s"] + cold, m["alive"])
-
-    def _jax_decide(self, fns, snap):
-        m = snap.fn_matrix(fns, self.perf, self.placement)
-        return ps.warm_decide(m["exec_s"], m["data_s"], m["warm_free"],
-                              snap.cold_start_s, m["alive"])
-
-    @staticmethod
-    def cascade(feats, params):
-        alive = feats["alive"]
-        cold = np.where(feats["warm_free"] > 0.0, 0.0,
-                        _row(feats["cold_start_s"]))
-        kill = np.where(~alive, KILL_DEAD, 0).astype(np.uint8)
-        return feats["exec_s"] + feats["data_s"] + cold, kill
+    def _features(self, fns, snap):
+        return {**snap.fn_matrix(fns, self.perf, self.placement),
+                "cold_start_s": snap.cold_start_s}
 
 
 def _slo_vector(fns: Sequence[FunctionSpec]) -> np.ndarray:
@@ -661,31 +661,14 @@ class EnergyAwarePolicy(Policy):
     """§5.2: among platforms predicted to meet the SLO, pick the one with
     the lowest predicted energy per invocation (the 17x edge result)."""
     name = "energy_aware"
+    kernel = staticmethod(ps.energy)
 
     def __init__(self, perf: FunctionPerformanceModel):
         self.perf = perf
 
-    def fn_cost_matrix(self, fns, snap):
-        m = snap.fn_matrix(fns, self.perf, p90=True, energy=True)
-        feasible = m["alive"] & (m["p90_s"] <= _slo_vector(fns)[:, None])
-        feasible = np.where(feasible.any(axis=1, keepdims=True), feasible,
-                            m["alive"])
-        return _masked(m["energy_j"], feasible)
-
-    def _jax_decide(self, fns, snap):
-        m = snap.fn_matrix(fns, self.perf, p90=True, energy=True)
-        return ps.energy_decide(m["energy_j"], m["p90_s"],
-                                _slo_vector(fns), m["alive"])
-
-    @staticmethod
-    def cascade(feats, params):
-        alive = feats["alive"]
-        feasible = alive & (feats["p90_s"] <= feats["slo_s"][:, None])
-        feasible = np.where(feasible.any(axis=1, keepdims=True), feasible,
-                            alive)
-        kill = (np.where(~alive, KILL_DEAD, 0) |
-                np.where(alive & ~feasible, KILL_SLO, 0)).astype(np.uint8)
-        return feats["energy_j"], kill
+    def _features(self, fns, snap):
+        return {**snap.fn_matrix(fns, self.perf, p90=True, energy=True),
+                "slo_s": _slo_vector(fns)}
 
 
 class SLOCompositePolicy(Policy):
@@ -695,6 +678,9 @@ class SLOCompositePolicy(Policy):
     + energy tie-break."""
 
     name = "slo_composite"
+    kernel = staticmethod(ps.slo_composite)
+    CASCADE_PARAMS = {"cpu_threshold": 0.9, "mem_threshold": 0.95,
+                      "energy_weight": 0.1}
 
     def __init__(self, perf: FunctionPerformanceModel,
                  placement: Optional[DataPlacementManager] = None,
@@ -706,27 +692,11 @@ class SLOCompositePolicy(Policy):
         self.mem_threshold = mem_threshold
         self.energy_weight = energy_weight
 
-    def _unloaded(self, snap):
-        return (snap.cpu_util < self.cpu_threshold) & \
-            (snap.mem_util < self.mem_threshold)
-
-    def _columns(self, fns, snap):
-        return snap.fn_matrix(fns, self.perf, self.placement,
-                              p90=True, energy=True)
-
-    def fn_cost_matrix(self, fns, snap):
-        m = self._columns(fns, snap)
-        # (1) utilization filter (§5.1.2)
-        ok = m["alive"] & self._unloaded(snap)[None, :]
-        ok = np.where(ok.any(axis=1, keepdims=True), ok, m["alive"])
-        # (2) SLO feasibility (§5.1.1)
-        feasible = ok & (m["p90_s"] <= _slo_vector(fns)[:, None])
-        feasible = np.where(feasible.any(axis=1, keepdims=True), feasible,
-                            ok)
-        # (3) locality-adjusted latency + energy tie-break (§5.1.4, §5.2)
-        cost = (m["exec_s"] + m["data_s"]) + \
-            self.energy_weight * m["energy_j"]
-        return _masked(cost, feasible)
+    def _features(self, fns, snap):
+        return {**snap.fn_matrix(fns, self.perf, self.placement,
+                                 p90=True, energy=True),
+                "cpu_util": snap.cpu_util, "mem_util": snap.mem_util,
+                "slo_s": _slo_vector(fns)}
 
     def _jax_decide(self, fns, snap):
         """ONE fused jit step from raw estimator state: snapshot
@@ -735,8 +705,7 @@ class SLOCompositePolicy(Policy):
         never materializes exec/P90/energy matrices on this path.  The
         operands go to the device in one packed buffer and the choices
         come back in one copy, both inside ``_dispatch``: the result is
-        already on the host (the opt-in Pallas path returns device
-        arrays, which ``fn_decisions`` syncs)."""
+        already on the host."""
         tr = self.tracer
         if tr is None:
             return self._dispatch(self._gather(fns, snap))
@@ -744,10 +713,10 @@ class SLOCompositePolicy(Policy):
             args = self._gather(fns, snap)
         # the stats name the shape, so a compile inside the span can be
         # put down to the shape that caused it; ``bytes`` is the packed
-        # operand buffer (0 on the Pallas path, which packs nothing)
+        # operand buffer
         f, p = len(fns), snap.n
-        packed = 0 if ps.use_pallas() else 4 * ps.packed_words(f, p)
-        with tr("fdn/decide/dispatch", f=f, p=p, bytes=packed):
+        with tr("fdn/decide/dispatch", f=f, p=p,
+                bytes=4 * ps.packed_words(f, p)):
             return self._dispatch(args)
 
     def _gather(self, fns, snap):
@@ -757,36 +726,16 @@ class SLOCompositePolicy(Policy):
         nodes, loaded_w = snap.power
         return (est["ewma_v"], est["ewma_n"], est["analytic_s"],
                 est["resp_h2"], est["resp_n"], base["data_s"], nodes,
-                loaded_w, base["alive"], self._unloaded(snap),
+                loaded_w, base["alive"],
+                _unloaded(snap.cpu_util, snap.mem_util, self.cpu_threshold,
+                          self.mem_threshold),
                 _slo_vector(fns), self.energy_weight)
 
     @staticmethod
     def _dispatch(args):
         """Pack ``args``, transfer them, launch the kernel and copy the
-        choices back (the Pallas path: transfer and launch only)."""
-        if ps.use_pallas():
-            return ps.fused_composite_decide_pallas(*args)
+        choices back."""
         return ps.fused_composite_decide(*args)
-
-    CASCADE_PARAMS = {"cpu_threshold": 0.9, "mem_threshold": 0.95,
-                      "energy_weight": 0.1}
-
-    @staticmethod
-    def cascade(feats, params):
-        alive = feats["alive"]
-        unloaded = _row((feats["cpu_util"] < params["cpu_threshold"]) &
-                        (feats["mem_util"] < params["mem_threshold"]))
-        ok = alive & unloaded
-        ok = np.where(ok.any(axis=1, keepdims=True), ok, alive)
-        feasible = ok & (feats["p90_s"] <= feats["slo_s"][:, None])
-        feasible = np.where(feasible.any(axis=1, keepdims=True), feasible,
-                            ok)
-        cost = (feats["exec_s"] + feats["data_s"]) + \
-            params["energy_weight"] * feats["energy_j"]
-        kill = (np.where(~alive, KILL_DEAD, 0) |
-                np.where(alive & ~ok, KILL_UTIL, 0) |
-                np.where(ok & ~feasible, KILL_SLO, 0)).astype(np.uint8)
-        return cost, kill
 
 
 POLICIES = {cls.name: cls for cls in

@@ -18,7 +18,8 @@ Because the features are policy-agnostic, an offline what-if replay
 (``repro.obs.whatif``) can re-score the journaled columns under *any*
 stateless policy or alternate QoS config; re-scoring under the same
 policy reproduces the original choices byte-identically (the
-correctness oracle — the cascades mirror ``fn_cost_matrix`` op for op).
+correctness oracle — the live decision and the cascade are one
+definition, ``repro.kernels.policy_score``).
 
 The journal row id is stamped onto every invocation the decision routed
 (``Invocation.decision`` / ``InvocationBatch.decision`` ->
@@ -37,6 +38,7 @@ import numpy as np
 
 from repro.core.scheduler import (KILL_DEAD, KILL_SLO, KILL_UTIL,
                                   decision_features)
+from repro.kernels.policy_score import masked_cost
 
 # 2D float feature columns, (rows, Pmax), NaN-padded past each row's
 # platform-set size.  Order is the .npz layout contract.
@@ -229,8 +231,7 @@ class DecisionJournal:
             feats["alive"] = self._alive[sel, :P]
             feats["slo_s"] = self._slo_s[sel]
             cost, kill = self._cascade(feats, self.params)
-            masked = np.where((kill == 0) & np.isfinite(cost), cost,
-                              np.inf)
+            masked, _ = masked_cost(np, cost, kill == 0)
             ch = self._choice[sel]
             rest = masked.copy()
             rr = np.nonzero(ch >= 0)[0]

@@ -2,12 +2,12 @@
 
 ``replay`` re-scores a ``DecisionJournal``'s snapshot feature columns
 offline — no simulation re-run — under any stateless registry policy
-and/or alternate cascade params / scaled SLOs.  The policy ``cascade``
-staticmethods are pure functions of exactly the journaled features and
-mirror the live ``fn_cost_matrix`` arithmetic op for op, so replaying
-under the *same* policy and params reproduces the original (numpy-
-backend) choices byte-identically — ``replay_matches`` is the
-correctness oracle pinned by tests and the ``run.py explain`` flow.
+and/or alternate cascade params / scaled SLOs.  A policy's ``cascade``
+is a pure function of exactly the journaled features and the same
+definition the live decision runs, so replaying under the *same* policy
+and params reproduces the original (numpy-backend) choices
+byte-identically — ``replay_matches`` is the correctness oracle pinned
+by tests and the ``run.py explain`` flow.
 
 Journal rows are grouped by platform-set id; each group replays as one
 dense (rows, P) cascade + masked argmin, first-lowest tie-break —
@@ -21,6 +21,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from repro.core.scheduler import POLICIES
+from repro.kernels.policy_score import masked_argmin
 from repro.obs.provenance import FEATURE_COLS, DecisionJournal
 
 
@@ -101,10 +102,7 @@ def replay(journal: DecisionJournal,
         feats["alive"] = jc["alive"][mask][:, :P]
         feats["slo_s"] = jc["slo_s"][mask] * slo_scale
         cost, kill = cascade(feats, params)
-        masked = np.where((kill == 0) & np.isfinite(cost), cost, np.inf)
-        finite = np.isfinite(masked)
-        any_ok = finite.any(axis=1)
-        ch = np.argmin(masked, axis=1).astype(np.int16)
+        ch, any_ok = masked_argmin(np, cost, kill == 0)
         ch = np.where(any_ok, ch, -1).astype(np.int16)
         est = feats["exec_s"] + feats["data_s"]
         chosen_est = est[np.arange(ch.size), np.maximum(ch, 0)]

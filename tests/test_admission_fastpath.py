@@ -1,7 +1,6 @@
 """JIT-compiled admission fast path: jitted-vs-NumPy score-backend
 parity (every Policy subclass, byte-identical choices on seeded
-scenarios), the Pallas fused filter+argmin variant, grouped hedge timers
-vs per-invocation watchers, batched local-trigger delegation, and the
+scenarios), grouped hedge timers vs per-invocation watchers, batched local-trigger delegation, and the
 columnar drain's exact equivalence to sequential invokes."""
 import numpy as np
 import pytest
@@ -116,28 +115,6 @@ def test_jax_backend_matches_numpy_on_registry_scenarios():
             reports[backend] = run_scenario(registry.get(name)).to_json()
         assert reports["numpy"] == reports["jax"], \
             f"{name}: scenario report drifts across score backends"
-
-
-def test_pallas_composite_matches_numpy():
-    from repro.kernels import policy_score as ps
-    rng = np.random.default_rng(7)
-    cp, fns = build()
-    _randomized_state(cp, fns, rng)
-    invs = _mixed_invs(fns, rng, 64)
-    plats = list(cp.platforms.values())
-    sched.set_score_backend("numpy")
-    want = [p.prof.name if p else None for p in
-            SLOCompositePolicy(cp.perf, cp.placement).choose_batch(
-                invs, plats)]
-    sched.set_score_backend("jax")
-    ps.set_use_pallas(True)
-    try:
-        got = [p.prof.name if p else None for p in
-               SLOCompositePolicy(cp.perf, cp.placement).choose_batch(
-                   [Invocation(i.fn, 0.0) for i in invs], plats)]
-    finally:
-        ps.set_use_pallas(False)
-    assert got == want
 
 
 def test_fn_decisions_match_full_score_matrix():

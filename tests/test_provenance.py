@@ -7,10 +7,11 @@ Load-bearing invariants pinned here:
     under the journaled policy + params reproduces every original choice
     byte-identically, on all three prov/* acceptance scenarios AND for
     every stateless registry policy driven directly;
-  * backend parity — the jitted ``composite_explain`` kernel and the
-    host ``SLOCompositePolicy.cascade`` agree on choice / kill bits /
-    runner-up margin bit-for-bit on a dyadic input grid (values exactly
-    representable in float32, so the f32/f64 width difference vanishes);
+  * backend parity — the one SLO-composite definition under ``jax.jit``
+    (float32) and under NumPy (``SLOCompositePolicy.cascade``, float64)
+    agree on cost / kill bits / choice / ok bit-for-bit on a dyadic input
+    grid (values exactly representable in float32, so the f32/f64 width
+    difference vanishes);
   * join integrity — every completion stamped with a journal row id ran
     on exactly the platform that journal row chose;
   * persistence — ``save``/``load_journal`` round-trips every column and
@@ -52,6 +53,8 @@ except ImportError:  # the seeded sweep twin below still runs
         integers = _none
 
 try:
+    import jax
+    import jax.numpy as jnp
     from repro.kernels import policy_score as ps
     HAVE_JAX = True
 except Exception:
@@ -294,7 +297,7 @@ def test_save_load_roundtrip(tmp_path, prov_tiny):
 
 
 # ---------------------------------------------------------------------------
-# jitted-kernel vs host-cascade parity (numpy/jax backend identity)
+# one SLO-composite definition: jitted vs NumPy (backend identity)
 # ---------------------------------------------------------------------------
 
 # dyadic grid: every value is k/64 (and the energy weight 1/8), so the
@@ -320,42 +323,34 @@ def _dyadic_case(F, P, seed):
     return feats
 
 
-def _host_explain(feats):
+def _host_decision(feats):
     cost, kill = SLOCompositePolicy.cascade(feats, _PARAMS)
-    masked = np.where((kill == 0) & np.isfinite(cost), cost, np.inf)
-    choice = np.argmin(masked, axis=1)
-    ok = np.isfinite(masked).any(axis=1)
-    rest = masked.copy()
-    rest[np.arange(choice.size), choice] = np.inf
-    best2 = rest.min(axis=1)
-    has2 = np.isfinite(best2)
-    runner = np.where(has2, np.argmin(rest, axis=1), -1)
-    chosen = masked[np.arange(choice.size), choice]
-    with np.errstate(invalid="ignore"):   # inf - inf on all-dead rows
-        margin = np.where(has2, best2 - chosen, np.inf)
-    return choice, ok, kill, runner, margin, cost
+    choice, ok = ps.masked_argmin(np, cost, kill == 0)
+    return choice, ok, kill, cost
+
+
+def _device_decision(*cols):
+    """The same definition under ``jax.jit``: choice, ok, kill, cost."""
+    cost, stages = ps.slo_composite(jnp, *cols)
+    choice, ok = ps.masked_argmin(jnp, cost, stages[-1])
+    return choice, ok, ps.kill_bits(jnp, *stages), cost
 
 
 def _assert_backend_parity(F, P, seed):
     feats = _dyadic_case(F, P, seed)
-    h_choice, h_ok, h_kill, h_runner, h_margin, h_cost = \
-        _host_explain(feats)
+    h_choice, h_ok, h_kill, h_cost = _host_decision(feats)
     unloaded = (feats["cpu_util"] < _PARAMS["cpu_threshold"]) & \
         (feats["mem_util"] < _PARAMS["mem_threshold"])
-    out = ps.composite_explain(feats["exec_s"], feats["data_s"],
-                               feats["p90_s"], feats["energy_j"],
-                               feats["alive"], unloaded, feats["slo_s"],
-                               _PARAMS["energy_weight"])
-    choice, ok, kill, runner, margin, cost = \
-        (np.asarray(a) for a in out)
+    out = jax.jit(_device_decision)(
+        feats["alive"], feats["exec_s"], feats["data_s"], feats["p90_s"],
+        feats["energy_j"], unloaded[None, :], feats["slo_s"],
+        _PARAMS["energy_weight"])
+    choice, ok, kill, cost = (np.asarray(a) for a in out)
+    assert cost.dtype == np.float32
     np.testing.assert_array_equal(ok, h_ok)
     np.testing.assert_array_equal(kill, h_kill)
     np.testing.assert_array_equal(cost.astype(np.float64), h_cost)
     np.testing.assert_array_equal(choice[h_ok], h_choice[h_ok])
-    np.testing.assert_array_equal(margin.astype(np.float64)[h_ok],
-                                  h_margin[h_ok])
-    fin = h_ok & np.isfinite(h_margin)
-    np.testing.assert_array_equal(runner[fin], h_runner[fin])
 
 
 @pytest.mark.skipif(not HAVE_JAX, reason="jax kernels unavailable")

@@ -1,10 +1,9 @@
 """The admission path compiled for a described TPU v5e chip.
 
-Interpret mode cannot see what the chip's compiler refuses (Mosaic once
-refused the fused Pallas cascade for a select between two boolean
-vectors).  These tests compile the main path's device programs for one
-v5e chip of a described ``v5e:2x2`` topology — no chip is attached, so
-nothing runs — at the paper fleet's shape and at a pod-scale registry.
+A CPU run cannot see what the chip's compiler refuses.  These tests
+compile the main path's device programs for one v5e chip of a described
+``v5e:2x2`` topology — no chip is attached, so nothing runs — at the
+paper fleet's shape and at a pod-scale registry.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU compiler library, and
@@ -45,30 +44,6 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
-
-
-def _cascade_args(one_chip, f, p):
-    """Shapes of ``fused_composite_decide``'s arguments, in its order."""
-    def s(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    f32, i32 = jnp.float32, jnp.int32
-    return dict(ewma_v=s((f, p), f32), ewma_n=s((f, p), i32),
-                analytic_s=s((f, p), f32), resp_h2=s((f, p), f32),
-                resp_n=s((f, p), i32), data_s=s((f, p), f32),
-                nodes=s((p,), f32), loaded_w=s((p,), f32),
-                alive=s((f, p), jnp.bool_), unloaded=s((p,), jnp.bool_),
-                slo_s=s((f,), f32), energy_weight=s((), f32))
-
-
-@pytest.mark.parametrize("f,p", SHAPES)
-def test_fused_composite_pallas_lowers_to_mosaic(one_chip, f, p):
-    a = _cascade_args(one_chip, f, p)
-    args = (a["ewma_v"], a["ewma_n"], a["analytic_s"], a["resp_h2"],
-            a["resp_n"], a["data_s"], a["nodes"], a["loaded_w"],
-            a["energy_weight"], a["alive"], a["unloaded"], a["slo_s"])
-    compiled = ps._fused_composite_pallas.lower(
-        *args, interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("f,p", SHAPES)
