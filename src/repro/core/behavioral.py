@@ -71,36 +71,114 @@ class P2Quantile:
 
 def _p2_update(h: List[float], n: List[int], ns: List[float],
                q: float, x: float) -> None:
-    """One post-bootstrap P² marker update, in place on plain Python
-    lists/floats (the shared scalar core of ``P2Quantile`` and the
-    columnar cells in ``PerfState`` — identical arithmetic, bit-exact)."""
-    if x < h[0]:
-        h[0] = x
-        k = 0
-    elif x >= h[4]:
-        h[4] = x
-        k = 3
-    else:
-        k = next(i for i in range(4) if h[i] <= x < h[i + 1])
-    for i in range(k + 1, 5):
-        n[i] += 1
-    for i, d in enumerate((0, q / 2, q, (1 + q) / 2, 1)):
-        ns[i] += d
-    for i in (1, 2, 3):
-        d = ns[i] - n[i]
-        if (d >= 1 and n[i + 1] - n[i] > 1) or \
-           (d <= -1 and n[i - 1] - n[i] < -1):
-            d = 1 if d > 0 else -1
-            # parabolic
-            hp = h[i] + d / (n[i + 1] - n[i - 1]) * (
-                (n[i] - n[i - 1] + d) * (h[i + 1] - h[i]) /
-                (n[i + 1] - n[i]) +
-                (n[i + 1] - n[i] - d) * (h[i] - h[i - 1]) /
-                (n[i] - n[i - 1]))
-            if not h[i - 1] < hp < h[i + 1]:
-                hp = h[i] + d * (h[i + d] - h[i]) / (n[i + d] - n[i])
-            h[i] = hp
-            n[i] += d
+    """One post-bootstrap P² marker update: ``_p2_update_many`` of one
+    observation."""
+    _p2_update_many(h, n, ns, q, (x,))
+
+
+def _p2_update_many(h: List[float], n: List[int], ns: List[float],
+                    q: float, xs: Sequence[float]) -> None:
+    """Post-bootstrap P² marker updates of each of ``xs`` in order, in
+    place on plain Python lists of the heights ``h``, positions ``n`` and
+    desired positions ``ns`` — the one marker arithmetic of
+    ``P2Quantile``, the columnar cells in ``PerfState`` and telemetry's
+    sketches.
+
+    The markers live in locals for the whole run and are stored back
+    once.  Per observation: the cell search and the position increments
+    are unrolled (the heights stay sorted, so the first ``x < h[i]``
+    names the cell ``h[i-1] <= x < h[i]``), then markers 1, 2 and 3 move
+    toward their desired positions by the parabolic formula, or the
+    linear one where it leaves the neighbours' bracket, with the
+    textbook's operands in its order and its step ``d`` written out as
+    +1 or -1 (a product with -1 and an added negation are exact in IEEE
+    arithmetic), so each update is bit for bit the textbook's.  ``n[0]``
+    and ``ns[0]`` never move (the lowest marker's increment is 0);
+    ``ns[4]``, read by no adjustment, takes its whole-number increments
+    once at the end."""
+    h0, h1, h2, h3, h4 = h
+    n0, n1, n2, n3, n4 = n
+    w1, w2, w3 = ns[1], ns[2], ns[3]
+    a1, a2, a3 = q / 2, q, (1 + q) / 2
+    for x in xs:
+        if x < h0:
+            h0 = x
+            n1 += 1
+            n2 += 1
+            n3 += 1
+        elif x >= h4:
+            h4 = x
+        elif x < h1:
+            n1 += 1
+            n2 += 1
+            n3 += 1
+        elif x < h2:
+            n2 += 1
+            n3 += 1
+        elif x < h3:
+            n3 += 1
+        n4 += 1
+        w1 += a1
+        w2 += a2
+        w3 += a3
+        # marker 1, between markers 0 and 2
+        d = w1 - n1
+        if d >= 1 and n2 - n1 > 1:
+            hp = h1 + 1 / (n2 - n0) * (
+                (n1 - n0 + 1) * (h2 - h1) / (n2 - n1) +
+                (n2 - n1 - 1) * (h1 - h0) / (n1 - n0))
+            if not h0 < hp < h2:
+                hp = h1 + (h2 - h1) / (n2 - n1)
+            h1 = hp
+            n1 += 1
+        elif d <= -1 and n0 - n1 < -1:
+            hp = h1 - 1 / (n2 - n0) * (
+                (n1 - n0 - 1) * (h2 - h1) / (n2 - n1) +
+                (n2 - n1 + 1) * (h1 - h0) / (n1 - n0))
+            if not h0 < hp < h2:
+                hp = h1 - (h0 - h1) / (n0 - n1)
+            h1 = hp
+            n1 -= 1
+        # marker 2, between markers 1 and 3
+        d = w2 - n2
+        if d >= 1 and n3 - n2 > 1:
+            hp = h2 + 1 / (n3 - n1) * (
+                (n2 - n1 + 1) * (h3 - h2) / (n3 - n2) +
+                (n3 - n2 - 1) * (h2 - h1) / (n2 - n1))
+            if not h1 < hp < h3:
+                hp = h2 + (h3 - h2) / (n3 - n2)
+            h2 = hp
+            n2 += 1
+        elif d <= -1 and n1 - n2 < -1:
+            hp = h2 - 1 / (n3 - n1) * (
+                (n2 - n1 - 1) * (h3 - h2) / (n3 - n2) +
+                (n3 - n2 + 1) * (h2 - h1) / (n2 - n1))
+            if not h1 < hp < h3:
+                hp = h2 - (h1 - h2) / (n1 - n2)
+            h2 = hp
+            n2 -= 1
+        # marker 3, between markers 2 and 4
+        d = w3 - n3
+        if d >= 1 and n4 - n3 > 1:
+            hp = h3 + 1 / (n4 - n2) * (
+                (n3 - n2 + 1) * (h4 - h3) / (n4 - n3) +
+                (n4 - n3 - 1) * (h3 - h2) / (n3 - n2))
+            if not h2 < hp < h4:
+                hp = h3 + (h4 - h3) / (n4 - n3)
+            h3 = hp
+            n3 += 1
+        elif d <= -1 and n2 - n3 < -1:
+            hp = h3 - 1 / (n4 - n2) * (
+                (n3 - n2 - 1) * (h4 - h3) / (n4 - n3) +
+                (n4 - n3 + 1) * (h3 - h2) / (n3 - n2))
+            if not h2 < hp < h4:
+                hp = h3 - (h2 - h3) / (n2 - n3)
+            h3 = hp
+            n3 -= 1
+    h[:] = h0, h1, h2, h3, h4
+    n[1:] = n1, n2, n3, n4
+    ns[1:4] = w1, w2, w3
+    ns[4] += len(xs)
 
 
 class EWMA:
@@ -230,42 +308,35 @@ class PerfState(NamedTuple):
 def _q_add(qs: QuantileState, fi: int, pi: int, x: float, q: float) -> None:
     """Scalar P² add on one columnar cell — bit-exact ``P2Quantile.add``
     (cells round-trip through float64, which is lossless)."""
-    c = int(qs.count[fi, pi])
-    qs.count[fi, pi] = c + 1
-    if c < 5:
-        qs.buf[fi, pi, c] = x
-        if c == 4:
-            s = sorted(float(v) for v in qs.buf[fi, pi])
-            qs.heights[fi, pi] = s
-            qs.pos[fi, pi] = (0, 1, 2, 3, 4)
-            qs.want[fi, pi] = (0, 2 * q, 4 * q, 2 + 2 * q, 4)
-        return
-    h = qs.heights[fi, pi].tolist()
-    n = qs.pos[fi, pi].tolist()
-    ns = qs.want[fi, pi].tolist()
-    _p2_update(h, n, ns, q, x)
-    qs.heights[fi, pi] = h
-    qs.pos[fi, pi] = n
-    qs.want[fi, pi] = ns
+    _q_add_many(qs, fi, pi, (x,), q)
 
 
 def _q_add_many(qs: QuantileState, fi: int, pi: int, xs: Sequence[float],
                 q: float) -> None:
     """``_q_add`` of each of ``xs`` in order, bit for bit: the bootstrap
-    rows one by one, then every later row on one load of the cell's
-    markers into Python lists, stored back once."""
+    rows one by one (the fifth sorts the window into markers), then every
+    later row in one ``_p2_update_many`` pass over the cell's markers,
+    loaded once into Python lists and stored back once."""
+    m = len(xs)
+    if m == 0:
+        return
+    c = int(qs.count[fi, pi])
+    qs.count[fi, pi] = c + m
     k = 0
-    while k < len(xs) and int(qs.count[fi, pi]) < 5:
-        _q_add(qs, fi, pi, xs[k], q)
+    while c < 5 and k < m:
+        qs.buf[fi, pi, c] = xs[k]
+        c += 1
         k += 1
-    if k == len(xs):
+        if c == 5:
+            qs.heights[fi, pi] = sorted(float(v) for v in qs.buf[fi, pi])
+            qs.pos[fi, pi] = (0, 1, 2, 3, 4)
+            qs.want[fi, pi] = (0, 2 * q, 4 * q, 2 + 2 * q, 4)
+    if k == m:
         return
     h = qs.heights[fi, pi].tolist()
     n = qs.pos[fi, pi].tolist()
     ns = qs.want[fi, pi].tolist()
-    for x in xs[k:]:
-        _p2_update(h, n, ns, q, x)
-    qs.count[fi, pi] += len(xs) - k
+    _p2_update_many(h, n, ns, q, xs[k:] if k else xs)
     qs.heights[fi, pi] = h
     qs.pos[fi, pi] = n
     qs.want[fi, pi] = ns
@@ -438,33 +509,42 @@ class FunctionPerformanceModel:
             self._ewma_cell_add(st.cold_v, st.cold_n, pi, inv.queue_time)
         self.version += 1
 
-    def observe_many(self, invs: Sequence[Invocation]) -> None:
+    def observe_many(self, invs: Sequence[Invocation]) -> int:
         """``observe`` of each invocation in order, bit for bit, with one
-        load and one store of each (function, platform) cell: the EWMAs
-        run row by row in Python floats with ``observe``'s expressions,
-        the P² markers through ``_q_add_many``.  Cells are disjoint, so
-        only the per-platform cold-start EWMA needs the rows' own order,
-        and it keeps it.  An overridden ``observe`` (a subclass, a
-        wrapper) is called row by row instead."""
+        load and one store of each (function, platform) cell.  One pass
+        over the rows groups them by cell, collecting each cell's exec
+        and response times and the cold rows; then each cell's EWMA runs
+        in Python floats with ``observe``'s expressions, and each of its
+        two P² runs takes one ``_p2_update_many`` pass.  Cells are
+        disjoint, so only the per-platform cold-start EWMA needs the
+        rows' own order, and it keeps it.  An overridden ``observe`` (a
+        subclass, a wrapper) is called row by row instead.  Returns the
+        number of cells whose markers were loaded (one per row on the
+        row-by-row path)."""
         if type(self).observe is not _OBSERVE:
             for inv in invs:
                 self.observe(inv)
-            return
-        cells: Dict[Tuple[str, str], List[Invocation]] = {}
+            return len(invs)
+        cells: Dict[Tuple[str, str], Tuple[List[float], List[float]]] = {}
+        cold: List[Tuple[str, float]] = []
         for inv in invs:
-            key = (inv.fn.name, inv.platform or "?")
-            rows = cells.get(key)
-            if rows is None:
-                cells[key] = [inv]
-            else:
-                rows.append(inv)
+            plat = inv.platform
+            key = (inv.fn.name, plat or "?")
+            cell = cells.get(key)
+            if cell is None:
+                cell = cells[key] = ([], [])
+            cell[0].append(inv.exec_time)
+            rt = inv.response_time
+            if rt is not None:
+                cell[1].append(rt)
+            if inv.cold_start and plat:
+                cold.append((plat, inv.queue_time))
         # rows and columns are assigned (and the arrays grown) in the
         # order the rows first name them, as row-by-row observes would
         idx = [self._cell(*key) for key in cells]
         st = self._state
         a, b = self.ALPHA, 1 - self.ALPHA
-        for (fi, pi), rows in zip(idx, cells.values()):
-            xs = [inv.exec_time for inv in rows]
+        for (fi, pi), (xs, rts) in zip(idx, cells.values()):
             c = int(st.exec_n[fi, pi])
             v = float(st.exec_v[fi, pi])
             for x in xs:
@@ -473,14 +553,12 @@ class FunctionPerformanceModel:
             st.exec_v[fi, pi] = v
             st.exec_n[fi, pi] = c
             _q_add_many(st.exec_q, fi, pi, xs, self.Q)
-            _q_add_many(st.resp_q, fi, pi,
-                        [rt for inv in rows
-                         if (rt := inv.response_time) is not None], self.Q)
-        for inv in invs:
-            if inv.cold_start and inv.platform:
-                self._ewma_cell_add(st.cold_v, st.cold_n,
-                                    self._pcol[inv.platform], inv.queue_time)
+            _q_add_many(st.resp_q, fi, pi, rts, self.Q)
+        pcol = self._pcol
+        for plat, qt in cold:
+            self._ewma_cell_add(st.cold_v, st.cold_n, pcol[plat], qt)
         self.version += len(invs)
+        return len(cells)
 
     def fold_observations(self, fn_name: str, platform_name: str,
                           exec_s: float, resp_s: float, k: int) -> None:

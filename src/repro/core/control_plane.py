@@ -681,7 +681,12 @@ class FDNControlPlane:
     def _on_complete_many(self, invs: List[Invocation]):
         """``_on_complete`` of each invocation in order (its folds touch
         disjoint state, so each runs over the whole group in turn)."""
-        self.perf.observe_many(invs)
+        tr = self.tracer
+        if tr is None:
+            self.perf.observe_many(invs)
+        else:
+            with tr("fdn/observe", rows=len(invs)) as span:
+                span.set_metadata(cells=self.perf.observe_many(invs))
         completed = self.hedge.completed
         for inv in invs:
             completed(inv)

@@ -48,6 +48,10 @@ The spans, by site (stats in brackets).  ``fdn/complete`` and the
                           trigger [rows: rows completed; grouped: rows that
                           took the group's fold]
         fdn/launch        the rows a folded group's drains start [rows]
+        fdn/observe       the performance model's fold of a folded group
+                          (FunctionPerformanceModel.observe_many) [rows:
+                          rows observed; cells: (function, platform) cells
+                          whose P² markers were loaded]
       fdn/drain           the drain each row completed alone triggers
 
 The tap sites in ``repro.core`` spell the names out, since ``repro.core``
@@ -66,6 +70,7 @@ ENQUEUE = "fdn/enqueue"
 DRAIN = "fdn/drain"
 LAUNCH = "fdn/launch"
 COMPLETE = "fdn/complete"
+OBSERVE = "fdn/observe"
 ADVANCE = "fdn/advance"
 
 # every span name, and the stats each one carries
@@ -80,5 +85,6 @@ STATS = {
     DRAIN: ("started", "materialized"),
     LAUNCH: ("rows",),
     COMPLETE: ("rows", "grouped"),
+    OBSERVE: ("rows", "cells"),
     ADVANCE: ("events",),
 }

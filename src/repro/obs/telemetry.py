@@ -37,7 +37,7 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
-from repro.core.behavioral import QuantileState, _p2_update, _q_value
+from repro.core.behavioral import QuantileState, _q_add_many, _q_value
 
 __all__ = ["TelemetryConfig", "TierRing", "SeriesRollup", "TelemetryEngine",
            "HEALTH_METRICS"]
@@ -50,40 +50,6 @@ HEALTH_METRICS = ("queue_depth", "utilization", "watts")
 
 # fn-slot placeholder for per-platform (fn-less) health series
 NO_FN = "-"
-
-
-def _q_add_many(qs: QuantileState, slot: int, xs, q: float) -> None:
-    """Feed a whole bucket's samples into one P² cell with a single
-    load/store of the marker state.  Bit-identical to looping
-    ``behavioral._q_add`` (the cells round-trip through float64, which
-    is lossless) but ~10x cheaper per sample — the per-call array
-    round-trip dominated streaming-replay folds."""
-    c = int(qs.count[slot, 0])
-    n = len(xs)
-    if n == 0:
-        return
-    qs.count[slot, 0] = c + n
-    i = 0
-    while c < 5 and i < n:
-        qs.buf[slot, 0, c] = xs[i]
-        c += 1
-        i += 1
-        if c == 5:
-            s = sorted(float(v) for v in qs.buf[slot, 0])
-            qs.heights[slot, 0] = s
-            qs.pos[slot, 0] = (0, 1, 2, 3, 4)
-            qs.want[slot, 0] = (0, 2 * q, 4 * q, 2 + 2 * q, 4)
-    if i >= n:
-        return
-    h = [float(v) for v in qs.heights[slot, 0]]
-    pos = [int(v) for v in qs.pos[slot, 0]]
-    want = [float(v) for v in qs.want[slot, 0]]
-    while i < n:
-        _p2_update(h, pos, want, q, float(xs[i]))
-        i += 1
-    qs.heights[slot, 0] = h
-    qs.pos[slot, 0] = pos
-    qs.want[slot, 0] = want
 
 
 def _q_add_block(qs: QuantileState, slots: np.ndarray, X: np.ndarray,
@@ -273,7 +239,9 @@ class TierRing:
         if hi > self.maxs[slot]:
             self.maxs[slot] = hi
         self.bad[slot] += bad
-        _q_add_many(self.sketch, slot, q_feed, self.quantile)
+        # the slot's cell, column 0, fed in one P² pass
+        _q_add_many(self.sketch, slot, 0, [float(v) for v in q_feed],
+                    self.quantile)
         return True
 
     def accumulate_block(self, bids: np.ndarray, counts: np.ndarray,

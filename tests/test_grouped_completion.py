@@ -211,9 +211,9 @@ def test_grouped_fold_matches_per_row_path(seed):
 
 # ------------------------------------------------------- batch folds ---
 
-def _observations(rng, n, nf, npl):
+def _observations(rng, n, nf, npl, cold=0.2):
     """``n`` completed invocations over ``nf`` functions and ``npl``
-    platforms, a share of them cold."""
+    platforms, a share ``cold`` of them cold."""
     specs = [FunctionSpec(f"f{i}") for i in range(nf)]
     out = []
     for _ in range(n):
@@ -222,7 +222,7 @@ def _observations(rng, n, nf, npl):
         inv.platform = f"p{int(rng.integers(npl))}"
         inv.exec_time = float(rng.lognormal(0.0, 1.0))
         inv.queue_time = float(rng.exponential(0.5))
-        inv.cold_start = bool(rng.random() < 0.2)
+        inv.cold_start = bool(rng.random() < cold)
         inv.end_t = inv.arrival_t + inv.queue_time + inv.exec_time
         inv.qos = int(rng.integers(0, 3))
         inv.tenant = int(rng.integers(0, 4))
@@ -239,18 +239,77 @@ def _chunks(rng, invs):
         i += k
 
 
-@pytest.mark.parametrize("seed,nf,npl", [(0, 3, 2), (1, 40, 3), (2, 5, 11),
-                                         (3, 1, 1)])
-def test_observe_many_matches_observe(seed, nf, npl):
+def _interleaved(rng, invs):
+    """The rows reordered round-robin over their (function, platform)
+    cells, so every group alternates between cells."""
+    cells = {}
+    for inv in invs:
+        cells.setdefault((inv.fn.name, inv.platform), []).append(inv)
+    rows = []
+    while cells:
+        for key in list(cells):
+            rows.append(cells[key].pop())
+            if not cells[key]:
+                del cells[key]
+    return rows
+
+
+def _shaped(shape, rng, invs):
+    """The rows in order and the groups ``observe_many`` folds them in:
+
+    * ``random``: groups of 1 to 23 rows;
+    * ``interleaved``: rows alternating between cells, in groups of 8 to
+      39, so each group runs several cells at once;
+    * ``bootstrap``: rows alternating between cells, in groups of 3, 4
+      and then 7 rows per cell, so every cell's fourth to seventh rows
+      (the bootstrap's end and the first marker updates) share a group;
+    * ``cold_mix``: half the rows cold, some with no platform (folded
+      into the ``?`` column, with no cold-start sample) and some with no
+      end (no response time), in groups of 1 to 23 rows.
+    """
+    if shape == "cold_mix":
+        for inv in invs[::7]:
+            inv.platform = None
+        for inv in invs[3::11]:
+            inv.end_t = None
+    if shape in ("interleaved", "bootstrap"):
+        invs = _interleaved(rng, invs)
+    if shape in ("random", "cold_mix"):
+        return invs, list(_chunks(rng, invs))
+    ncells = len({(inv.fn.name, inv.platform) for inv in invs})
+    if shape == "interleaved":
+        sizes = iter(lambda: int(rng.integers(8, 40)), None)
+    else:
+        sizes = iter([3 * ncells, 4 * ncells] + [7 * ncells] * len(invs))
+    groups, i = [], 0
+    while i < len(invs):
+        k = next(sizes)
+        groups.append(invs[i:i + k])
+        i += k
+    return invs, groups
+
+
+@pytest.mark.parametrize("seed,nf,npl,shape", [
+    pytest.param(0, 3, 2, "random", id="0-3-2"),
+    pytest.param(1, 40, 3, "random", id="1-40-3"),
+    pytest.param(2, 5, 11, "random", id="2-5-11"),
+    pytest.param(3, 1, 1, "random", id="3-1-1"),
+    pytest.param(4, 4, 3, "interleaved", id="interleaved-4-4-3"),
+    pytest.param(5, 3, 2, "bootstrap", id="bootstrap-5-3-2"),
+    pytest.param(6, 2, 5, "bootstrap", id="bootstrap-6-2-5"),
+    pytest.param(7, 4, 4, "cold_mix", id="cold_mix-7-4-4")])
+def test_observe_many_matches_observe(seed, nf, npl, shape):
     """Sequences that cross the five-sample P² bootstrap in several cells,
-    with cold starts, and (40 functions, 11 platforms) grow the arrays."""
+    with cold starts, and (40 functions, 11 platforms) grow the arrays;
+    groups that interleave cells, that cross a cell's bootstrap inside
+    the group, and that mix cold and warm rows across platforms."""
     rng = np.random.default_rng(seed)
-    invs = _observations(rng, 600, nf, npl)
+    invs, groups = _shaped(shape, rng, _observations(
+        rng, 600, nf, npl, cold=0.5 if shape == "cold_mix" else 0.2))
     one, many = FunctionPerformanceModel(), FunctionPerformanceModel()
     for inv in invs:
         one.observe(inv)
-    for chunk in _chunks(rng, invs):
-        many.observe_many(chunk)
+    cells = [many.observe_many(group) for group in groups]
     a, b = one._state, many._state
     assert a.exec_n.shape == b.exec_n.shape
     for x, y in zip((a.exec_v, a.exec_n, a.cold_v, a.cold_n, *a.exec_q,
@@ -260,6 +319,26 @@ def test_observe_many_matches_observe(seed, nf, npl):
         assert _bits(x) == _bits(y)
     assert one._frow == many._frow and one._pcol == many._pcol
     assert one.version == many.version == len(invs)
+    # each group loaded each of its cells once
+    assert cells == [len({(inv.fn.name, inv.platform or "?")
+                          for inv in group}) for group in groups]
+    if shape != "random":
+        assert max(c for c in cells) > 1
+    if shape == "bootstrap":
+        # every cell's run in the second group crosses its bootstrap
+        seen = {}
+        for inv in groups[0]:
+            key = (inv.fn.name, inv.platform)
+            seen[key] = seen.get(key, 0) + 1
+        after = dict(seen)
+        for inv in groups[1]:
+            key = (inv.fn.name, inv.platform)
+            after[key] = after.get(key, 0) + 1
+        assert all(seen[k] < 5 < after[k] for k in after)
+    if shape == "cold_mix":
+        assert int(a.cold_n.sum()) == sum(
+            1 for inv in invs if inv.cold_start and inv.platform)
+        assert 0 < int(a.resp_q.count.sum()) < int(a.exec_q.count.sum())
 
 
 def test_observe_many_calls_an_overridden_observe():

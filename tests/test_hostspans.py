@@ -113,7 +113,7 @@ def test_spans_nest_as_documented(traced):
         hs.GATHER: {hs.DECIDE}, hs.DISPATCH: {hs.DECIDE},
         hs.SYNC: {hs.DECIDE}, hs.ENQUEUE: {hs.ADMIT},
         hs.DRAIN: {hs.ENQUEUE, hs.ADVANCE},
-        hs.LAUNCH: {hs.DRAIN, hs.COMPLETE},
+        hs.LAUNCH: {hs.DRAIN, hs.COMPLETE}, hs.OBSERVE: {hs.COMPLETE},
         hs.COMPLETE: {hs.ADVANCE}, hs.ADVANCE: {None}}
     for (name, *_), par in zip(spans, parent):
         assert par in allowed[name], (name, par)
@@ -149,6 +149,13 @@ def test_once_per_call_and_counters(traced):
     assert grouped and single
     assert all(c["rows"] == c["grouped"] > 1 for c in grouped)
     assert all(c["rows"] == 1 for c in single)
+    # one fdn/observe inside each folded group, over its rows, loading
+    # at least one and at most one cell per row
+    observes = [s[3] for s in spans if s[0] == hs.OBSERVE]
+    assert [o["rows"] for o in observes] == [c["rows"] for c in grouped]
+    assert all(1 <= o["cells"] <= o["rows"] for o in observes)
+    assert sum(o["cells"] for o in observes) < sum(o["rows"]
+                                                   for o in observes)
     drains = [s for s, p in zip(spans, parent) if s[0] == hs.DRAIN]
     assert sum(1 for s, p in zip(spans, parent)
                if s[0] == hs.DRAIN and p == hs.ADVANCE) >= len(single)
@@ -189,3 +196,55 @@ def test_attach_reaches_platforms_added_later():
     cp.attach_tracer(None)
     assert all(p.tracer is None for p in cp.platforms.values())
     assert cp.policy.tracer is None and cp.clock.tracer is None
+
+
+class _Names:
+    """A tracer that keeps each span's name and stats (no clock)."""
+
+    def __init__(self):
+        self.spans = []
+
+    def __call__(self, name, **stats):
+        self.spans.append((name, dict(stats)))
+        return _Stats(self.spans[-1][1])
+
+
+class _Stats:
+    def __init__(self, rec):
+        self.rec = rec
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **stats):
+        self.rec.update(stats)
+
+
+def test_observe_span_once_per_fold_and_none_detached():
+    """Attached, every folded fdn/complete holds one fdn/observe over its
+    rows; detached, groups still fold through the performance model and
+    no span opens."""
+    cp, fns, _sink = _build()
+    folds = []
+    observe_many = cp.perf.observe_many
+    cp.perf.observe_many = lambda invs: folds.append(len(invs)) or \
+        observe_many(invs)
+    names = cp.attach_tracer(_Names())
+    _drive(cp, fns, seed=5, windows=3)
+    spans = names.spans
+    grouped = [st for n, st in spans if n == hs.COMPLETE and st["grouped"]]
+    observes = [st for n, st in spans if n == hs.OBSERVE]
+    assert grouped and [o["rows"] for o in observes] == \
+        [g["rows"] for g in grouped] == folds
+    assert all(set(o) == set(hs.STATS[hs.OBSERVE]) for o in observes)
+    assert all(1 <= o["cells"] <= o["rows"] for o in observes)
+    assert int(cp.perf._state.exec_n.sum()) == cp.completed_count
+    cp.attach_tracer(None)
+    n_spans, n_folds = len(spans), len(folds)
+    cp.submit_batch(_batch(fns, np.random.default_rng(6), 60,
+                           cp.clock.now()))
+    cp.clock.run_until(cp.clock.now() + 60.0)
+    assert len(folds) > n_folds and len(names.spans) == n_spans
